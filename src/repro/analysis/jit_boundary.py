@@ -535,7 +535,8 @@ class _FunctionAnalyzer:
             if (isinstance(base, ast.Attribute)
                     and isinstance(base.value, ast.Name)
                     and base.value.id == "jax"):
-                return True
+                # jax.sharding.* returns meshes and shardings: static
+                return base.attr != "sharding"
             # method on a traced object (reshape/astype/at...) -> traced
             if self._expr(func.value):
                 return True

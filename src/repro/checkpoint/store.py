@@ -24,10 +24,12 @@ import queue
 import re
 import threading
 import warnings
+import zlib
 from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
+import zstandard
 
 
 def _fault_injector():
@@ -40,30 +42,15 @@ def _fault_injector():
     mod = sys.modules.get("repro.core.resilience.faults")
     return mod.active() if mod is not None else None
 
-try:
-    import zstandard
-except ImportError:  # container image without python-zstandard
-    zstandard = None
-import zlib
 
 PyTree = Any
 
 
-def _compress(data: bytes) -> tuple:
-    if zstandard is not None:
-        return "zstd", zstandard.ZstdCompressor(level=3).compress(data)
-    return "zlib", zlib.compress(data, 3)
-
-
 def _decompress(codec: str, buf: bytes) -> bytes:
-    if codec == "zstd":
-        if zstandard is None:
-            raise RuntimeError(
-                "checkpoint was written with zstd but zstandard is not installed")
-        return zstandard.ZstdDecompressor().decompress(buf)
-    if codec == "zlib":
-        return zlib.decompress(buf)
-    raise ValueError(f"unknown checkpoint codec {codec!r}")
+    if codec != "zstd":
+        raise ValueError(f"unknown checkpoint codec {codec!r}")
+    return zstandard.ZstdDecompressor().decompress(buf)
+
 
 _SEP = "__"
 
@@ -143,12 +130,12 @@ def save(directory: str, step: int, state: PyTree) -> str:
     manifest = {"step": step, "format": 2, "leaves": {}}
     for key, leaf in flat.items():
         arr = np.asarray(jax.device_get(leaf))
-        codec, payload = _compress(arr.tobytes(order="C"))
-        fn = re.sub(r"[^\w.\-]", "_", key) + (
-            ".npy.zst" if codec == "zstd" else ".npy.zz")
+        payload = zstandard.ZstdCompressor(level=3).compress(
+            arr.tobytes(order="C"))
+        fn = re.sub(r"[^\w.\-]", "_", key) + ".npy.zst"
         manifest["leaves"][key] = {
             "file": fn, "shape": list(arr.shape), "dtype": str(arr.dtype),
-            "codec": codec, "bytes": len(payload),
+            "codec": "zstd", "bytes": len(payload),
             "crc32": _zlib_crc32(payload),
         }
         fpath = os.path.join(tmp, fn)

@@ -528,6 +528,7 @@ class RemoteAgent:
         return self._transport.submit(
             run_task_body, d.fn, tuple(d.args), kwargs,
             len(devices), d.mesh_shape, d.mesh_axes,
+            kind=d.kind,
             service_control=d.control if d.service else None,
             on_done=lambda fut, t=task, lu=lease_uid:
                 self._on_remote_exit(t, lu, fut),

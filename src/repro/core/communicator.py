@@ -60,11 +60,8 @@ def build_communicator(
     if want != n:
         raise ValueError(f"mesh shape {mesh_shape} needs {want} devices, got {n}")
     arr = np.asarray(devices).reshape(mesh_shape)
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5 explicit-axes API
-        mesh = Mesh(arr, mesh_axes,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(mesh_axes))
-    else:
-        mesh = Mesh(arr, mesh_axes)
+    mesh = Mesh(arr, mesh_axes,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh_axes))
     backend = "ici" if devices and devices[0].platform == "tpu" else "host"
     return Communicator(mesh, backend, time.time() - t0, tuple(devices),
                         pilot_uid=pilot_uid)

@@ -60,6 +60,8 @@ import time
 from concurrent.futures import Future
 from typing import Any, Callable, Deque, Dict, List, Optional
 
+import jax
+
 from repro.core.exec import pickling, protocol
 from repro.core.resilience import faults as rfaults
 from repro.core.resilience.policy import FailurePolicy
@@ -134,6 +136,11 @@ class SubprocessTransport(Transport):
     #: agent switches to the picklable remote-dispatch path on this flag
     remote = True
 
+    #: task kinds whose bodies need the accelerator.  Workers run JAX on
+    #: their own CPU (a chip belongs to one process, and on a TPU host the
+    #: parent holds it), so such a task would quietly run on the CPU there.
+    DEVICE_KINDS = ("train", "inference")
+
     _pool_seq = itertools.count()
 
     def __init__(self, max_workers: int = 2, *,
@@ -206,6 +213,7 @@ class SubprocessTransport(Transport):
     # -- public --------------------------------------------------------------
 
     def submit(self, fn: Callable, *args,
+               kind: Optional[str] = None,
                service_control=None,
                on_done: Optional[Callable[[Future], None]] = None,
                label: Optional[str] = None,
@@ -221,8 +229,14 @@ class SubprocessTransport(Transport):
         thread, so callers may hold scheduling locks while submitting.
         ``attempt_timeout_s`` (default: the transport policy's) bounds
         how long this attempt may run once dispatched before the monitor
-        declares the worker hung and fails the Future.
+        declares the worker hung and fails the Future.  A ``kind`` in
+        ``DEVICE_KINDS`` raises ``RuntimeError`` when this process runs on
+        a TPU: the workers could only run it on their CPU.
         """
+        if kind in self.DEVICE_KINDS and jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"{self.name} workers run JAX on their own CPU; refusing a "
+                f"{kind!r} task on a TPU host (use the in-process transport)")
         pickling.ensure_picklable(fn, args, kwargs, transport=self.name)
         payload = pickling.format_payload(
             fn, args, kwargs, service=service_control is not None)

@@ -15,12 +15,9 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.dataframe import ops_local as L
 from repro.dataframe.table import Table
-
-from repro.common.compat import axis_size
 
 
 def _specs_for(table: Table):
@@ -31,7 +28,7 @@ def _specs_for(table: Table):
 def _bucket_exchange(cols: Dict, valid, dest: jnp.ndarray, axis: str, cap: int):
     """Per-shard: route rows to destination shards with per-dest capacity
     ``cap``; returns received (cols, valid, n_dropped)."""
-    PIDX = axis_size(axis)
+    PIDX = jax.lax.axis_size(axis)
     # position of each row within its destination bucket
     onehot = jax.nn.one_hot(jnp.where(valid, dest, PIDX), PIDX + 1, dtype=jnp.int32)
     pos = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
@@ -77,12 +74,12 @@ def shuffle(table: Table, key: str, *, capacity_factor: float = 2.0):
     cap = max(int(per / nshards * capacity_factor), 16)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(_specs_for(table), P(axis)),
         out_specs=(_specs_for(table), P(axis), P()),
     )
     def _shuf(cols, valid):
-        dest = (L.hash_u32(cols[key]) % jnp.uint32(axis_size(axis))).astype(jnp.int32)
+        dest = (L.hash_u32(cols[key]) % jnp.uint32(jax.lax.axis_size(axis))).astype(jnp.int32)
         recv, rvalid, dropped = _bucket_exchange(cols, valid, dest, axis, cap)
         return recv, rvalid, dropped[None]
 
@@ -101,12 +98,12 @@ def sort(table: Table, key: str, *, capacity_factor: float = 2.5,
     cap = max(int(per * capacity_factor / nshards), 16)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(_specs_for(table), P(axis)),
         out_specs=(_specs_for(table), P(axis), P()),
     )
     def _sort(cols, valid):
-        nsh = axis_size(axis)
+        nsh = jax.lax.axis_size(axis)
         cols, valid = L.sort_by_key(cols, valid, key)
         keys = cols[key]
         big = jnp.iinfo(keys.dtype).max
@@ -147,12 +144,12 @@ def join(left: Table, right: Table, key: str, *, capacity_factor: float = 2.0):
                 for k, v in out_cols_proto.items()}
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(_specs_for(left), P(axis), _specs_for(right), P(axis)),
         out_specs=(out_spec, P(axis), P()),
     )
     def _join(lc, lv, rc, rv):
-        nsh = axis_size(axis)
+        nsh = jax.lax.axis_size(axis)
         ldest = (L.hash_u32(lc[key]) % jnp.uint32(nsh)).astype(jnp.int32)
         rdest = (L.hash_u32(rc[key]) % jnp.uint32(nsh)).astype(jnp.int32)
         lrecv, lrv, ldrop = _bucket_exchange(lc, lv, ldest, axis, capL)
@@ -171,7 +168,7 @@ def groupby_sum(table: Table, key: str, value_cols: Sequence[str],
     mesh, axis = table.mesh, table.axis
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(_specs_for(shuffled), P(axis)),
         out_specs=(P(axis), {c: P(axis) for c in value_cols}, P(axis)),
     )
@@ -189,7 +186,7 @@ def reduce_sum(table: Table, cols: Sequence[str]) -> Dict[str, float]:
     mesh, axis = table.mesh, table.axis
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(_specs_for(table.project(list(cols))), P(axis)),
         out_specs={c: P() for c in cols},
     )

@@ -15,10 +15,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.common.compat import axis_size
 
 
 def _quantize_int8(x: jnp.ndarray, block: int = 256):
@@ -79,9 +76,9 @@ def compressed_grad_sync(
         carry_in = g if ef is None else g + ef.astype(g.dtype)
 
         def sync(v):
-            return int8_psum(v, axis, block=block) / axis_size(axis)
+            return int8_psum(v, axis, block=block) / jax.lax.axis_size(axis)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             sync, mesh=mesh,
             in_specs=P(*([None] * g.ndim)),
             out_specs=P(*([None] * g.ndim)),

@@ -14,10 +14,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.common.compat import axis_size
 
 
 def pipeline_forward(
@@ -32,7 +29,7 @@ def pipeline_forward(
     n_micro = x_micro.shape[0]
 
     def per_stage(params_stage, queue):
-        S = axis_size(axis)
+        S = jax.lax.axis_size(axis)
         stage = jax.lax.axis_index(axis)
         ticks = n_micro + S - 1
         feat_shape = queue.shape[1:]
@@ -66,11 +63,11 @@ def pipeline_forward(
         (_, outputs), _ = jax.lax.scan(tick, (hold0, out0), jnp.arange(ticks))
         return outputs[None]  # [1, n_micro, ...] per stage
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     stacked = fn(stage_params, x_micro)  # [S, n_micro, ...]
     return stacked[-1]

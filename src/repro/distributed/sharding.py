@@ -149,9 +149,7 @@ class Axes:
 
 def constrain(x: jax.Array, axes: Sequence[Optional[str]], rules: Optional[dict] = None):
     """with_sharding_constraint via logical axes; no-op outside a mesh ctx."""
-    from repro.common.compat import current_mesh
-
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:  # pragma: no cover - outside jit/mesh
         return x
     r = merge_rules(rules)

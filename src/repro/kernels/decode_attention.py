@@ -7,6 +7,15 @@ the partials.  GPU→TPU adaptation: per-SM split-K becomes grid programs
 over VMEM-resident cache tiles; the GQA head group is packed into one MXU
 matmul ([G, D] x [D, block_k]) instead of warp-level broadcast.
 
+Each grid program is one (batch row, KV span) pair and reads the span's
+block over ALL KV heads, ``(1, block_k, KV, D)``, then loops over the
+heads inside the kernel.  Mosaic tiles the last two block dims in
+(sublane, lane) units and accepts a block only when each of them is a
+multiple of (8, 128) or spans the whole array dim; ``(KV, D)`` spans both,
+so the cache-native ``[.., S, KV, D]`` layout needs no transpose.  The
+span length is picked from the shapes (``kv_block``) so the
+double-buffered K and V blocks fit the default scoped VMEM.
+
 Two layouts, one kernel family:
 
 * ``decode_attention`` — contiguous cache rows ``[B, S, KV, D]`` (the
@@ -42,6 +51,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# bytes of double-buffered K and V blocks one program may hold in VMEM:
+# half of the 16 MiB default scoped limit, leaving room for q, the
+# outputs and (prefill) the per-head accumulators
+KV_BLOCK_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def kv_row_bytes(kv: int, d: int, dtype) -> int:
+    """VMEM bytes of one cache position ``[KV, D]`` once Mosaic tiles it:
+    sublanes pad to 8 rows of 32-bit words (16 for bf16), lanes to 128."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * max(4 // itemsize, 1)
+    return -(-kv // sub) * sub * -(-d // 128) * 128 * itemsize
+
+
+def kv_block(seq: int, block_k: int, kv: int, d: int, dtype) -> int:
+    """KV span per grid program: at most ``block_k``, small enough that
+    K and V double-buffered fit ``KV_BLOCK_VMEM_BYTES``, and dividing
+    ``seq`` (cache rows are power-of-two buckets on the serving path)."""
+    block_k = max(min(block_k, seq), 1)
+    row = kv_row_bytes(kv, d, dtype)
+    while block_k > 8 and 4 * block_k * row > KV_BLOCK_VMEM_BYTES:
+        block_k //= 2
+    while seq % block_k:
+        block_k //= 2
+    return block_k
+
 
 def _partial_softmax(q, k, v, kpos, cache_len, scale: float, window: int):
     """One split-K partial: q [G,D], k/v [bk,D], kpos [G,bk] int32 ->
@@ -65,47 +100,78 @@ def _partial_softmax(q, k, v, kpos, cache_len, scale: float, window: int):
     return m, l, acc
 
 
-def _write_neutral(m_ref, l_ref, acc_ref):
-    m_ref[0, 0, ...] = jnp.full_like(m_ref[0, 0], NEG_INF)
-    l_ref[0, 0, ...] = jnp.zeros_like(l_ref[0, 0])
-    acc_ref[0, 0, ...] = jnp.zeros_like(acc_ref[0, 0])
+def _dec_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
+                scale: float, block_k: int, window: int):
+    """One (row, KV span) program over every KV head: q [1, KV, G, D],
+    k/v [1, block_k, KV, D] -> partials [1, 1, KV, G, {1, 1, D}]."""
+    b = pl.program_id(0)
+    sj = pl.program_id(1)
+    cache_len = len_ref[b]
+    lo = sj * block_k
+    live = lo < cache_len
+    if window:
+        live = jnp.logical_and(live, lo + block_k > cache_len - window)
+    kv, g = q_ref.shape[1], q_ref.shape[2]
+
+    @pl.when(live)
+    def _compute():
+        kpos = lo + jax.lax.broadcasted_iota(jnp.int32, (g, block_k), 1)
+        for h in range(kv):  # static head index: a strided VMEM read
+            q = q_ref[0, h].astype(jnp.float32)            # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)      # [bk, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            m, l, acc = _partial_softmax(q, k, v, kpos, cache_len, scale,
+                                         window)
+            m_ref[0, 0, h] = m
+            l_ref[0, 0, h] = l
+            acc_ref[0, 0, h] = acc
+
+    @pl.when(jnp.logical_not(live))
+    def _skip():  # span entirely outside the valid prefix (or window)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def _combine_splits(m_p, l_p, acc_p, B, KV, G, D, dtype):
-    """Merge split-K partials [B*KV, ns, G, ...] -> [B, H, D]."""
+def _split_k_call(kernel, q, cache_len, kv_operands, kv_spec, ns: int,
+                  prefetch, interpret: bool):
+    """Shared pallas_call of both layouts: grid (B, ns), one program per
+    (row, KV span); partials combined in jnp into [B, H, D]."""
+    B, H, D = q.shape
+    KV = kv_operands[0].shape[-2]
+    G = H // KV
+    q_r = q.reshape(B, KV, G, D)
+    lens = jnp.broadcast_to(
+        jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
+    idx = lambda b, sj, *_: (b, 0, 0, 0)              # noqa: E731
+    out_idx = lambda b, sj, *_: (b, sj, 0, 0, 0)      # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 + len(prefetch),
+        grid=(B, ns),
+        in_specs=[pl.BlockSpec((1, KV, G, D), idx), kv_spec, kv_spec],
+        out_specs=[
+            pl.BlockSpec((1, 1, KV, G, 1), out_idx),
+            pl.BlockSpec((1, 1, KV, G, 1), out_idx),
+            pl.BlockSpec((1, 1, KV, G, D), out_idx),
+        ],
+    )
+    m_p, l_p, acc_p = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, ns, KV, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, KV, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, KV, G, D), jnp.float32),
+        ],
+        interpret=interpret,
+    )(lens, *prefetch, q_r, *kv_operands)
+    # merge the split-K partials
     m_all = jnp.max(m_p, axis=1, keepdims=True)
     w = jnp.exp(m_p - m_all)
     l_tot = jnp.sum(l_p * w, axis=1)
     acc = jnp.sum(acc_p * w, axis=1)
     out = acc / jnp.maximum(l_tot, 1e-30)
-    return out.reshape(B, KV * G, D).astype(dtype)
-
-
-def _dec_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
-                scale: float, block_k: int, window: int, kv: int):
-    bh = pl.program_id(0)
-    sj = pl.program_id(1)
-    cache_len = len_ref[bh // kv]
-    lo = sj * block_k
-    live = lo < cache_len
-    if window:
-        live = jnp.logical_and(live, lo + block_k > cache_len - window)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, ...].astype(jnp.float32)          # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        kpos = lo + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], block_k), 1)
-        m, l, acc = _partial_softmax(q, k, v, kpos, cache_len, scale, window)
-        m_ref[0, 0, ...] = m
-        l_ref[0, 0, ...] = l
-        acc_ref[0, 0, ...] = acc
-
-    @pl.when(jnp.logical_not(live))
-    def _skip():  # split-K block entirely outside the valid prefix
-        _write_neutral(m_ref, l_ref, acc_ref)
+    return out.reshape(B, H, D).astype(q.dtype)
 
 
 @functools.partial(
@@ -121,76 +187,14 @@ def decode_attention(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    B, H, D = q.shape
-    S, KV = k.shape[1], k.shape[2]
-    G = H // KV
-    scale = 1.0 / math.sqrt(D)
-    block_k = min(block_k, S)
-    ns = pl.cdiv(S, block_k)
-    if S % block_k:  # ragged tail: fall back to one block (S is max_len —
-        block_k = S  # always a power-of-two bucket on the serving path)
-        ns = 1
-
-    q_r = q.reshape(B, KV, G, D).reshape(B * KV, G, D)
-    lens = jnp.broadcast_to(
-        jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * KV, ns),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, sj, lr: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda bh, sj, lr: (bh // KV, sj, bh % KV, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda bh, sj, lr: (bh // KV, sj, bh % KV, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, 1), lambda bh, sj, lr: (bh, sj, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda bh, sj, lr: (bh, sj, 0, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda bh, sj, lr: (bh, sj, 0, 0)),
-        ],
-    )
-    m_p, l_p, acc_p = pl.pallas_call(
-        functools.partial(_dec_kernel, scale=scale, block_k=block_k,
-                          window=window, kv=KV),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KV, ns, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV, ns, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV, ns, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lens, q_r, k, v)
-    return _combine_splits(m_p, l_p, acc_p, B, KV, G, D, q.dtype)
-
-
-def _dec_paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref,
-                      m_ref, l_ref, acc_ref, *,
-                      scale: float, page_size: int, window: int, kv: int):
-    bh = pl.program_id(0)
-    sj = pl.program_id(1)
-    cache_len = len_ref[bh // kv]
-    lo = sj * page_size
-    live = lo < cache_len
-    if window:
-        live = jnp.logical_and(live, lo + page_size > cache_len - window)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, ...].astype(jnp.float32)          # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        kpos = lo + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], page_size), 1)
-        m, l, acc = _partial_softmax(q, k, v, kpos, cache_len, scale, window)
-        m_ref[0, 0, ...] = m
-        l_ref[0, 0, ...] = l
-        acc_ref[0, 0, ...] = acc
-
-    @pl.when(jnp.logical_not(live))
-    def _skip():  # page past the valid prefix (incl. unallocated sentinels)
-        _write_neutral(m_ref, l_ref, acc_ref)
+    S, KV, D = k.shape[1], k.shape[2], k.shape[3]
+    block_k = kv_block(S, block_k, KV, D, k.dtype)
+    kv_spec = pl.BlockSpec((1, block_k, KV, D),
+                           lambda b, sj, lr: (b, sj, 0, 0))
+    kernel = functools.partial(_dec_kernel, scale=1.0 / math.sqrt(D),
+                               block_k=block_k, window=window)
+    return _split_k_call(kernel, q, cache_len, (k, v), kv_spec,
+                         S // block_k, (), interpret)
 
 
 @functools.partial(
@@ -206,50 +210,20 @@ def decode_attention_paged(
     window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    B, H, D = q.shape
-    num_pages, page_size, KV, _ = k_pages.shape
-    max_pages = block_table.shape[1]
-    G = H // KV
-    scale = 1.0 / math.sqrt(D)
-
-    q_r = q.reshape(B, KV, G, D).reshape(B * KV, G, D)
-    lens = jnp.broadcast_to(
-        jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
+    num_pages, page_size, KV, D = k_pages.shape
     # clamp sentinel entries in-range: they only ever address positions at
     # or past cache_len, which the in-kernel mask discards
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
 
-    def page_map(bh, sj, lr, btr):
+    def page_map(b, sj, lr, btr):
         # gather the physical page through the block table (the paged read)
-        return (btr[bh // KV, sj], 0, bh % KV, 0)
+        return (btr[b, sj], 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * KV, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, sj, lr, btr: (bh, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, D), page_map),
-            pl.BlockSpec((1, page_size, 1, D), page_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, 1), lambda bh, sj, lr, btr: (bh, sj, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda bh, sj, lr, btr: (bh, sj, 0, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda bh, sj, lr, btr: (bh, sj, 0, 0)),
-        ],
-    )
-    m_p, l_p, acc_p = pl.pallas_call(
-        functools.partial(_dec_paged_kernel, scale=scale,
-                          page_size=page_size, window=window, kv=KV),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KV, max_pages, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV, max_pages, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV, max_pages, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lens, bt, q_r, k_pages, v_pages)
-    return _combine_splits(m_p, l_p, acc_p, B, KV, G, D, q.dtype)
+    def kernel(len_ref, bt_ref, *rest):
+        del bt_ref  # consumed by the index maps
+        _dec_kernel(len_ref, *rest, scale=1.0 / math.sqrt(D),
+                    block_k=page_size, window=window)
 
-
-def _dec_kernel_shapes():  # for docs/tests
-    return dict(block_k=512)
+    return _split_k_call(kernel, q, cache_len, (k_pages, v_pages),
+                         pl.BlockSpec((1, page_size, KV, D), page_map),
+                         block_table.shape[1], (bt,), interpret)

@@ -1,7 +1,7 @@
-"""jit'd dispatch wrappers: ``impl="auto"`` -> Pallas on TPU, interpret-mode
-Pallas or the jnp reference elsewhere.  The model code calls these; the
-dry-run lowers the ref path (XLA:CPU cannot codegen Mosaic), real TPU runs
-take the kernel path."""
+"""jit'd dispatch wrappers: ``impl="auto"`` -> Pallas on TPU, the jnp
+reference elsewhere (``impl="interpret"`` selects interpret-mode Pallas).
+The model code calls these; the dry-run lowers the ref path (XLA:CPU
+cannot codegen Mosaic), real TPU runs take the kernel path."""
 from __future__ import annotations
 
 from typing import Optional
@@ -18,11 +18,9 @@ from repro.kernels import rmsnorm as _rms
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):  # pragma: no cover - backend probe:
-        # RuntimeError = no backend initialised, IndexError = zero devices
-        return False
+    # no fallback: a backend that fails to initialise raises here rather
+    # than quietly sending a TPU host down the reference path
+    return jax.default_backend() == "tpu"
 
 
 def _resolve(impl: str) -> str:

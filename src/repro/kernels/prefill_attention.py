@@ -27,6 +27,12 @@ Two layouts, mirroring the decode kernels:
   logical-page) grid step — PR 5's paged-read pattern, now on the
   prefill side.
 
+Each grid program is one (batch row, query tile, KV span) and reads the
+span's block over ALL KV heads, ``(1, block_k, KV, D)`` — the layout
+Mosaic tiles without a transpose (see ``decode_attention``) — then loops
+over the query heads inside the kernel with per-head running softmax
+state in VMEM scratch.
+
 Outputs at padding query rows (``i >= chunk_lens[b]``) are exact zeros
 in both the kernels and the jnp oracles, so parity tests compare full
 tensors.  Queries attend nothing outside ``kpos <= base + i`` — for a
@@ -43,7 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (KV_BLOCK_VMEM_BYTES, NEG_INF,
+                                            kv_block, kv_row_bytes)
 
 
 def write_chunk(cache: jnp.ndarray, new: jnp.ndarray, base: jnp.ndarray,
@@ -82,14 +89,17 @@ def write_chunk_paged(pages: jnp.ndarray, block_table: jnp.ndarray,
 
 def _pf_kernel(base_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
                m_scr, l_scr, acc_scr, *,
-               scale: float, block_q: int, block_k: int, heads: int):
-    bh = pl.program_id(0)
+               scale: float, block_q: int, block_k: int):
+    """q/o [1, H, bq, D] (head-major), k/v [1, bk, KV, D]; scratch holds
+    each head's running (max, denom, acc) across the KV-span axis."""
+    b = pl.program_id(0)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     ns = pl.num_programs(2)
-    b = bh // heads
     base = base_ref[b]
     clen = clen_ref[b]
+    H, KV = q_ref.shape[1], k_ref.shape[2]
+    G = H // KV
 
     @pl.when(kj == 0)
     def _init():
@@ -105,51 +115,90 @@ def _pf_kernel(base_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, ...].astype(jnp.float32)        # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                    # [bq, bk]
         qpos = base + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        kpos = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            jnp.int32, (block_q, block_k), 0)
+        kpos = lo + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
         mask = kpos <= qpos
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # a fully-masked row (padding query) must contribute l = 0
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = m_new
+        for kv in range(KV):  # static head index: a strided VMEM read
+            k = k_ref[0, :, kv, :].astype(jnp.float32)    # [bk, D]
+            v = v_ref[0, :, kv, :].astype(jnp.float32)
+            for h in range(kv * G, (kv + 1) * G):
+                q = q_ref[0, h].astype(jnp.float32)       # [bq, D]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                                 # [bq, bk]
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
+                                                    keepdims=True))
+                # a fully-masked row (padding query) must contribute l = 0
+                p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                m_scr[h] = m_new
 
     @pl.when(kj == ns - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        out = acc_scr[...] / l
         row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, out.shape, 0)
-        # padding query rows are exact zeros (oracle parity)
-        o_ref[0, ...] = jnp.where(row < clen, out, 0.0).astype(o_ref.dtype)
+            jnp.int32, acc_scr.shape[1:], 0)
+        for h in range(H):
+            out = acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+            # padding query rows are exact zeros (oracle parity)
+            o_ref[0, h] = jnp.where(row < clen, out, 0.0).astype(o_ref.dtype)
 
 
-def _prep_q(q, block_q):
-    """[B, T, H, D] (model-native) -> padded [B*H, Tp, D] + grid sizes."""
+def _q_tile(T: int, block_q: int, H: int, D: int, kv_bytes: int) -> int:
+    """Query rows per program: at most ``block_q``, shrunk while q, o (both
+    double-buffered, counted at 4 bytes) and the f32 scratch — whose
+    ``[bq, 1]`` max/denom columns pad to 128 lanes — overflow the VMEM the
+    K/V blocks leave free."""
+    block_q = max(min(block_q, T), 1)
+    lanes = -(-D // 128) * 128
+    while block_q > 8 and (
+            kv_bytes + H * block_q * (5 * lanes + 2 * 128) * 4
+            > 2 * KV_BLOCK_VMEM_BYTES):
+        block_q //= 2
+    return block_q
+
+
+def _call(kernel, q, base, clens, kv_operands, kv_spec, ns: int,
+          prefetch, block_q: int, block_k: int, interpret: bool):
+    """Shared pallas_call of both layouts: grid (B, q tiles, KV spans)."""
     B, T, H, D = q.shape
-    block_q = min(block_q, max(T, 1))
+    KV = kv_operands[0].shape[-2]
+    kv_bytes = 4 * block_k * kv_row_bytes(KV, D, kv_operands[0].dtype)
+    block_q = _q_tile(T, block_q, H, D, kv_bytes)
     Tp = pl.cdiv(T, block_q) * block_q
-    q_r = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    q_r = q.transpose(0, 2, 1, 3)                      # [B, H, T, D]
     if Tp != T:
-        q_r = jnp.pad(q_r, ((0, 0), (0, Tp - T), (0, 0)))
-    return q_r, block_q, Tp
+        q_r = jnp.pad(q_r, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+    q_idx = lambda b, qi, kj, *_: (b, 0, qi, 0)       # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 + len(prefetch),
+        grid=(B, Tp // block_q, ns),
+        in_specs=[pl.BlockSpec((1, H, block_q, D), q_idx), kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, H, block_q, D), q_idx),
+        scratch_shapes=[
+            pltpu.VMEM((H, block_q, 1), jnp.float32),   # running max
+            pltpu.VMEM((H, block_q, 1), jnp.float32),   # running denom
+            pltpu.VMEM((H, block_q, D), jnp.float32),   # output accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, scale=1.0 / math.sqrt(D), block_q=block_q,
+                          block_k=block_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Tp, D), q.dtype),
+        interpret=interpret,
+    )(base, clens, *prefetch, q_r, *kv_operands)
+    return out[:, :, :T].transpose(0, 2, 1, 3)
 
 
 def _vec(x, B):
@@ -173,48 +222,17 @@ def prefill_attention(
     interpret: bool = False,
 ):
     """Returns ``(out [B, T, H, D], k_cache', v_cache')``."""
-    B, T, H, D = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
-    G = H // KV
-    scale = 1.0 / math.sqrt(D)
+    B = q.shape[0]
+    S, KV, D = k_cache.shape[1], k_cache.shape[2], k_cache.shape[3]
     base = _vec(base, B)
     clens = _vec(chunk_lens, B)
     kc = write_chunk(k_cache, k_new, base, clens)
     vc = write_chunk(v_cache, v_new, base, clens)
-
-    block_k = min(block_k, S)
-    while S % block_k:  # cache rows are power-of-two buckets on the
-        block_k //= 2   # serving path; degrade gracefully otherwise
-    q_r, block_q, Tp = _prep_q(q, block_q)
-    grid = (B * H, Tp // block_q, S // block_k)
-
-    def kv_map(bh, qi, kj, br, cr):
-        return (bh // H, kj, (bh % H) // G, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj, br, cr: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, kj, br, cr: (bh, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_pf_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, heads=H),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype),
-        interpret=interpret,
-    )(base, clens, q_r, kc, vc)
-    out = out[:, :T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    block_k = kv_block(S, block_k, KV, D, kc.dtype)
+    kv_spec = pl.BlockSpec((1, block_k, KV, D),
+                           lambda b, qi, kj, br, cr: (b, kj, 0, 0))
+    out = _call(_pf_kernel, q, base, clens, (kc, vc), kv_spec,
+                S // block_k, (), block_q, block_k, interpret)
     return out, kc, vc
 
 
@@ -233,11 +251,8 @@ def prefill_attention_paged(
     interpret: bool = False,
 ):
     """Returns ``(out [B, T, H, D], k_pages', v_pages')``."""
-    B, T, H, D = q.shape
-    num_pages, page_size, KV, _ = k_pages.shape
-    max_pages = block_table.shape[1]
-    G = H // KV
-    scale = 1.0 / math.sqrt(D)
+    B = q.shape[0]
+    num_pages, page_size, KV, D = k_pages.shape
     base = _vec(base, B)
     clens = _vec(chunk_lens, B)
     kp = write_chunk_paged(k_pages, block_table, k_new, base, clens)
@@ -246,41 +261,17 @@ def prefill_attention_paged(
     # clamp sentinels in-range: they only address positions at or past a
     # row's live prefix, which the causal mask / block skip discards
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
-    q_r, block_q, Tp = _prep_q(q, block_q)
-    grid = (B * H, Tp // block_q, max_pages)
 
-    def page_map(bh, qi, kj, br, cr, btr):
-        return (btr[bh // H, kj], 0, (bh % H) // G, 0)
+    def page_map(b, qi, kj, br, cr, btr):
+        return (btr[b, kj], 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D),
-                         lambda bh, qi, kj, br, cr, btr: (bh, qi, 0)),
-            pl.BlockSpec((1, page_size, 1, D), page_map),
-            pl.BlockSpec((1, page_size, 1, D), page_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, kj, br, cr, btr: (bh, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-    )
-    def paged_kernel(base_ref, clen_ref, bt_ref, *rest):
+    def paged_kernel(base_ref, clen_ref, bt_ref, *rest, **kw):
         # bt_ref is consumed by the BlockSpec index maps above; the body
         # only needs the per-row base/chunk lengths
         del bt_ref
-        _pf_kernel(base_ref, clen_ref, *rest, scale=scale,
-                   block_q=block_q, block_k=page_size, heads=H)
+        _pf_kernel(base_ref, clen_ref, *rest, **kw)
 
-    out = pl.pallas_call(
-        paged_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype),
-        interpret=interpret,
-    )(base, clens, bt, q_r, kp, vp)
-    out = out[:, :T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    out = _call(paged_kernel, q, base, clens, (kp, vp),
+                pl.BlockSpec((1, page_size, KV, D), page_map),
+                block_table.shape[1], (bt,), block_q, page_size, interpret)
     return out, kp, vp

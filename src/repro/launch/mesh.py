@@ -6,11 +6,8 @@ import jax
 
 
 def _mesh(dev_array, axes):
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5 explicit-axes API
-        return jax.sharding.Mesh(
-            dev_array, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(dev_array, axes)
+    return jax.sharding.Mesh(
+        dev_array, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

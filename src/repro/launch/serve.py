@@ -25,8 +25,10 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import RunConfig
 from repro.core import Session, stage
@@ -70,10 +72,11 @@ def run_fleet(args, cfg) -> dict:
 
     slots = args.slots or min(args.batch, 4)
     max_len = args.prompt_len + args.gen + 1
+    # one engine per device, round-robin when engines outnumber devices
     router = build_fleet(cfg, RunConfig(), num_engines=args.fleet,
                          disaggregate=args.disaggregate, seed=0,
                          max_slots=slots, max_len=max_len,
-                         name_prefix="gateway")
+                         name_prefix="gateway", devices=jax.devices())
     router.start()
     try:
         rng = np.random.default_rng(1)
@@ -100,6 +103,8 @@ def run_fleet(args, cfg) -> dict:
         "routed": stats.get("routed", 0),
         "handoffs": stats.get("handoffs_routed", 0),
         "router": stats,
+        "streams": [list(r.tokens) for r in requests],
+        "engine_devices": [m.engine.device for m in router.members],
     }
     spread = {k.split("routed_to.")[1]: v for k, v in stats.items()
               if k.startswith("routed_to.")}
@@ -121,7 +126,8 @@ def engine_service(ctx, arch: str = "tinyllama-1.1b", smoke: bool = True,
     "Cross-process execution")."""
     cfg = get_config(arch, smoke=smoke)
     engine = ServeEngine(cfg, RunConfig(), max_slots=max_slots,
-                         max_len=max_len, seed=seed)
+                         max_len=max_len, seed=seed,
+                         device=ctx.comm.devices[0])
     return engine.run_service(ctx.control, resume_state=ctx.resume_state)
 
 
@@ -173,6 +179,7 @@ def run(args) -> dict:
         stats = task.result
         lat = sorted(r.latency_s for r in requests)
         ttft = sorted(r.ttft_s for r in requests)
+        itl = sorted(g for r in requests for g in r.inter_token_s) or [0.0]
         n_tok = sum(len(r.tokens) for r in requests)
         res = {
             "requests": len(requests),
@@ -181,10 +188,12 @@ def run(args) -> dict:
             "latency_p50_s": lat[len(lat) // 2],
             "latency_max_s": lat[-1],
             "ttft_p50_s": ttft[len(ttft) // 2],
+            "inter_token_p50_s": itl[len(itl) // 2],
             "slot_occupancy": stats["slot_occupancy"],
             "engine": stats,
             "runtime_overheads": task.overhead_s,
             "preemptions": task.preemptions,
+            "streams": [list(r.tokens) for r in requests],
         }
         print(f"[serve] {cfg.name}: {res['tokens_per_s']:.1f} tok/s over "
               f"{len(requests)} reqs ({slots} slots, occupancy "
@@ -215,9 +224,11 @@ def build_parser():
                     choices=["in-process", "subprocess"],
                     help="where the service stage executes: this process, "
                          "or a worker daemon process with its own JAX "
-                         "runtime (repro.core.exec)")
+                         "runtime on the CPU (repro.core.exec; refused on "
+                         "a TPU host)")
     return ap
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(build_parser().parse_args())

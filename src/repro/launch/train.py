@@ -29,15 +29,14 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import RunConfig
 from repro.core import Session, stage
 from repro.core.pilot import PilotDescription, PilotManager
 from repro.dataframe.table import Table
-from repro.launch.mesh import make_mesh
-from repro.train.state import init_train_state, train_state_specs
+from repro.train.state import init_train_state
 from repro.train.step import make_train_step
-from repro.distributed.sharding import param_specs_tree, merge_rules
 
 
 def make_corpus(vocab: int, n_tokens: int, seed: int = 0) -> np.ndarray:
@@ -90,7 +89,16 @@ def run(args) -> dict:
     @stage(kind="train", checkpoint=ckpt_dir)
     def train(ctx):  # noqa: PKL001 — in-process driver stage
         table = ctx.upstream["preprocess"]
-        state = init_train_state(jax.random.PRNGKey(args.seed), cfg, run_cfg)
+        # compute on the devices this stage leased: state replicated over
+        # the stage's mesh, batch split over its data axis where it divides
+        mesh = ctx.comm.mesh
+        replicated = NamedSharding(mesh, P())
+        batch_sharding = NamedSharding(
+            mesh, P("data") if args.batch % mesh.size == 0 else P())
+        with jax.default_device(ctx.comm.devices[0]):
+            state = init_train_state(jax.random.PRNGKey(args.seed), cfg,
+                                     run_cfg)
+        state = jax.device_put(state, replicated)
         start_step = 0
         # ctx.resume_step is threaded in by the agent on checkpoint-aware
         # retry (the stage declares checkpoint=); --resume covers the
@@ -99,21 +107,26 @@ def run(args) -> dict:
         if resume_from is None and args.resume:
             resume_from = store.latest_step(ckpt_dir)
         if resume_from is not None:
-            state = store.restore(ckpt_dir, state, step=resume_from)
+            state = jax.device_put(
+                store.restore(ckpt_dir, state, step=resume_from), replicated)
             start_step = int(state["step"])
             print(f"[train] resumed from step {start_step}")
         step_fn = jax.jit(make_train_step(cfg, run_cfg), donate_argnums=(0,))
         ckpt = store.AsyncCheckpointer(ckpt_dir, keep=2)
         tokens = table.col("tokens")
         n_rows = tokens.shape[0]
-        losses = []
+        losses, step_s = [], []
         t0 = time.time()
         for i in range(start_step, args.steps):
+            t_step = time.time()
             lo = (i * args.batch) % max(n_rows - args.batch, 1)
             chunk = jax.lax.dynamic_slice_in_dim(tokens, lo, args.batch, 0)
-            batch = {"tokens": chunk, "labels": jnp.roll(chunk, -1, axis=1)}
+            batch = jax.device_put(
+                {"tokens": chunk, "labels": jnp.roll(chunk, -1, axis=1)},
+                batch_sharding)
             state, metrics = step_fn(state, batch)
-            losses.append(float(metrics["loss"]))
+            losses.append(float(metrics["loss"]))  # waits for the step
+            step_s.append(time.time() - t_step)
             if args.ckpt_every and (i + 1) % args.ckpt_every == 0:
                 ckpt.save(i + 1, state)
             if (i + 1) % max(args.steps // 10, 1) == 0:
@@ -123,7 +136,9 @@ def run(args) -> dict:
         ckpt.save(args.steps, state)
         ckpt.close()
         return {"losses": losses, "state_step": int(state["step"]),
-                "train_s": time.time() - t0}
+                "train_s": time.time() - t0, "step_s": step_s,
+                "state_devices": sorted({d.id for leaf in jax.tree.leaves(state)
+                                         for d in leaf.devices()})}
 
     @stage(kind="inference")
     def postprocess(ctx):  # noqa: PKL001 — in-process driver stage
@@ -132,7 +147,8 @@ def run(args) -> dict:
         last = np.mean(r["losses"][-5:])
         return {"first_loss": float(first), "last_loss": float(last),
                 "improved": bool(last < first), "train_s": r["train_s"],
-                "steps": len(r["losses"])}
+                "steps": len(r["losses"]), "losses": r["losses"],
+                "step_s": r["step_s"], "state_devices": r["state_devices"]}
 
     # ONE pipeline regardless of pod layout: under --kind-pods the
     # preprocess stage resolves to pod-data and train/postprocess to
@@ -151,6 +167,8 @@ def run(args) -> dict:
     res["placement"] = pipe.stage_placements()
     res["kind_pods"] = {p.uid: sorted(p.task_kinds) for p in session.pilots} \
         if kind_pods else None
+    res["pod_devices"] = {p.uid: [d.id for d in p.alive_devices()]
+                          for p in session.pilots}
     print(f"[deep-rc] {cfg.name}: loss {res['first_loss']:.4f} -> "
           f"{res['last_loss']:.4f} in {res['steps']} steps "
           f"({res['train_s']:.1f}s); runtime overheads: {res['overheads']}; "
@@ -179,4 +197,5 @@ def build_parser():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(build_parser().parse_args())

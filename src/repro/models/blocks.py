@@ -239,8 +239,9 @@ def chunked_attention(
 
 def _decode_attn(cfg, q, k_cache, v_cache, cache_len, *, window: int = 0):
     """Single-step decode through the kernel dispatch (kernels/ops.py):
-    Pallas flash-decode on TPU, interpret-mode Pallas elsewhere, the
-    GSPMD-sharded jnp oracle under ``cfg.decode_impl="ref"``.
+    under the default ``cfg.decode_impl="auto"``, Pallas flash-decode on
+    TPU and the GSPMD-sharded jnp oracle elsewhere (``"interpret"`` runs
+    the kernel in interpret mode).
 
     q: [B,1,H,D]; caches: [B,Smax,KV,D]; cache_len: [] or [B] int32 —
     number of valid positions (including current).  A [B] vector gives

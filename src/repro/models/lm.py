@@ -381,8 +381,7 @@ def lm_apply(
                 # hoists the first-use f32 convert through the scan's
                 # dynamic-update-slice and stacks the residuals twice
                 # (bf16 + f32) — a 3x memory hit at 4k seq.
-                from repro.common.compat import optimization_barrier
-                x = optimization_barrier(x)
+                x = jax.lax.optimization_barrier(x)
                 if cfg.seq_parallel:
                     # Megatron SP: the saved residual is seq-sharded over
                     # the model axis (16x smaller stack); GSPMD inserts the

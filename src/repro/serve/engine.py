@@ -129,7 +129,8 @@ class ServeEngine:
                  decode_impl: Optional[str] = None,
                  prefill_chunk_tokens: Optional[int] = 64,
                  prefill_only: bool = False,
-                 name: Optional[str] = None):
+                 name: Optional[str] = None,
+                 device: Optional[jax.Device] = None):
         if cfg.is_encoder_decoder or cfg.input_kind != "tokens":
             raise NotImplementedError("ServeEngine targets token-LM archs")
         if cfg.mrope_sections:
@@ -168,9 +169,15 @@ class ServeEngine:
         # pages — admission backpressures on the free list)
         self.num_pages = (num_pages if num_pages is not None
                           else max_slots * self.max_pages)
-        self.params = (params if params is not None
-                       else init_params(jax.random.PRNGKey(seed),
-                                        model_specs(cfg)))
+        # the device holding this engine's params and KV cache (None:
+        # JAX's default device); fleet engines each get their own
+        self.device = device
+        with jax.default_device(device):
+            self.params = (params if params is not None
+                           else init_params(jax.random.PRNGKey(seed),
+                                            model_specs(cfg)))
+        if device is not None:
+            self.params = jax.device_put(self.params, device)
         if self.paged:
             # raises at construction for unsupported archs: paged caches
             # need attention-family temporal blocks
@@ -271,8 +278,9 @@ class ServeEngine:
                                                 range(self.max_slots)]
         else:
             specs = lm_cache_specs(self.cfg, self.max_slots, self.max_len)
-        self.cache = jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype),
-                                  specs, is_leaf=is_param)
+        self.cache = jax.tree.map(
+            lambda p: jnp.zeros(p.shape, p.dtype, device=self.device),
+            specs, is_leaf=is_param)
         self.lengths = np.zeros(self.max_slots, np.int32)
         self.last_tok = np.zeros(self.max_slots, np.int32)
         self.slots = [None] * self.max_slots
@@ -357,6 +365,15 @@ class ServeEngine:
                     np.int32)
                 self.free_pages = list(range(self.num_pages))
                 self.slot_pages = [[] for _ in range(self.max_slots)]
+
+    def place(self, device: jax.Device) -> None:
+        """Move params and KV cache onto ``device``: a pilot-mode engine is
+        built before the pilot that runs it is chosen."""
+        with self._lock:
+            self.device = device
+            self.params = jax.device_put(self.params, device)
+            if self.cache is not None:
+                self.cache = jax.device_put(self.cache, device)
 
     # -- client side ---------------------------------------------------------
 

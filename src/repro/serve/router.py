@@ -230,6 +230,7 @@ class EngineRouter:
             engine = m.engine
 
             def body(comm, *, control, resume_state=None, _e=engine):
+                _e.place(comm.devices[0])  # compute on the leased device
                 return _e.run_service(control, resume_state=resume_state)
 
             desc = TaskDescription(
@@ -658,11 +659,13 @@ def build_fleet(cfg: ModelConfig, run_cfg: Optional[RunConfig] = None, *,
                 seed: int = 0, name_prefix: str = "fleet",
                 router_kwargs: Optional[Dict[str, Any]] = None,
                 prefill_overrides: Optional[Dict[str, Any]] = None,
+                devices: Optional[Sequence[jax.Device]] = None,
                 **engine_kwargs) -> EngineRouter:
     """Construct N engines sharing one parameter set and wrap them in a
     router.  ``disaggregate=True`` splits roles: ``num_prefill``
     (default N//2, floored at 1) prefill-only engines feed the rest via
-    KV handoff.
+    KV handoff.  Engine ``i`` keeps its params and KV cache on
+    ``devices[i % len(devices)]`` (default: JAX's default device).
 
     Prefill engines default to WHOLE-PROMPT prefill
     (``prefill_chunk_tokens=None``): chunking exists to bound the decode
@@ -675,6 +678,10 @@ def build_fleet(cfg: ModelConfig, run_cfg: Optional[RunConfig] = None, *,
         raise ValueError("need num_engines >= 1")
     if params is None:
         params = init_params(jax.random.PRNGKey(seed), model_specs(cfg))
+
+    def _device(i):
+        return devices[i % len(devices)] if devices else None
+
     engines: List[ServeEngine] = []
     if disaggregate:
         if num_engines < 2:
@@ -692,13 +699,14 @@ def build_fleet(cfg: ModelConfig, run_cfg: Optional[RunConfig] = None, *,
             engines.append(ServeEngine(
                 cfg, run_cfg, params=params, prefill_only=pre,
                 name=f"{name_prefix}.{'pre' if pre else 'dec'}{i}",
-                **(pre_kw if pre else engine_kwargs)))
+                device=_device(i), **(pre_kw if pre else engine_kwargs)))
         roles = ["prefill" if i < np_ else "decode"
                  for i in range(num_engines)]
     else:
         for i in range(num_engines):
             engines.append(ServeEngine(
                 cfg, run_cfg, params=params,
-                name=f"{name_prefix}.eng{i}", **engine_kwargs))
+                name=f"{name_prefix}.eng{i}", device=_device(i),
+                **engine_kwargs))
         roles = ["any"] * num_engines
     return EngineRouter(engines, roles=roles, **(router_kwargs or {}))

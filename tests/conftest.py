@@ -2,53 +2,14 @@
 tests and benches see the container's single CPU device.  Tests that need a
 multi-device mesh (dataframe collectives, elastic FT, HLO SPMD analysis)
 run their body in a subprocess with XLA_FLAGS set (see tests/spawn/)."""
-import importlib.util
 import os
 import subprocess
 import sys
-import types
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPAWN = os.path.join(REPO, "tests", "spawn")
-
-# -- hypothesis fallback ------------------------------------------------------
-# Several modules do `from hypothesis import given, settings, strategies`.
-# The dependency is declared in pyproject.toml ([dev]), but collection must
-# never hard-fail on a bare environment: install a conftest-level stub that
-# turns every @given property test into a pytest skip while leaving the rest
-# of the module runnable.  (pytest.importorskip at module level would skip
-# the whole module, losing the non-property tests.)
-if importlib.util.find_spec("hypothesis") is None:
-    def _skip_given(*_a, **_k):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed (pip install -e .[dev])")(fn)
-        return deco
-
-    def _passthrough(*_a, **_k):
-        return lambda fn: fn
-
-    class _Strategy:
-        """Inert placeholder accepted at @given decoration time."""
-
-        def __getattr__(self, name):
-            return lambda *a, **k: self
-
-        def __call__(self, *a, **k):
-            return self
-
-    _strategies = types.ModuleType("hypothesis.strategies")
-    _strategies.__getattr__ = lambda name: lambda *a, **k: _Strategy()
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _skip_given
-    _hyp.settings = _passthrough
-    _hyp.strategies = _strategies
-    _hyp.__stub__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _strategies
 
 
 def run_spawned(script_name: str, devices: int = 8, timeout: int = 600):

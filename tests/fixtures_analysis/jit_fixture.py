@@ -9,7 +9,7 @@ Expected findings (asserted exactly in test_analysis_passes.py):
   ``static_argnames`` parameter (static-unhashable).
 
 ``clean_step`` exercises the exemptions the pass must honour: shape
-attributes, ``is None`` tests, and closure config are all static.
+attributes, ``jax.sharding`` queries, ``is None`` tests, closure config.
 """
 import functools
 import time
@@ -32,7 +32,7 @@ def leaky_step(x, scale):
 def clean_step(x, mask=None, bias=0.0):
     if mask is not None:  # static: identity test
         x = jnp.where(mask, x, 0.0)
-    if x.ndim > 1:  # static: shape-derived
+    if x.ndim > 1 and jax.sharding.get_abstract_mesh().empty:  # static
         x = x.reshape(-1)
     return x * bias
 

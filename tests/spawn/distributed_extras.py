@@ -6,7 +6,6 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.distributed.pipeline_par import pipeline_forward
 from repro.distributed.collectives import int8_psum, compressed_grad_sync
-from jax.experimental.shard_map import shard_map
 import functools
 
 # --- pipeline parallelism: 4 stages, stage i adds w[i] and doubles ---
@@ -29,7 +28,7 @@ print("pipeline_forward OK")
 mesh8 = make_mesh((8,), ("data",))
 g_local = jax.random.normal(jax.random.PRNGKey(1), (8, 1024)) * 0.01
 
-@functools.partial(shard_map, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
+@functools.partial(jax.shard_map, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
 def sync(g):
     return int8_psum(g[0], "data")[None] / 8.0
 

@@ -12,7 +12,6 @@ from repro.analysis import excepts, jit_boundary, locks, pickles
 from repro.analysis.findings import (
     Finding, diff_against_baseline, load_baseline, write_baseline,
 )
-from repro.analysis.kernel_contracts import blockspec_findings
 from repro.analysis.lockorder import LockOrderRecorder, instrument_runtime
 from repro.core.agent import RemoteAgent
 from repro.core.pilot import Pilot
@@ -60,23 +59,6 @@ def test_jit_pass_reports_exactly_the_seeded_violations():
     # every finding names the offending jit root; clean_step (shape
     # attrs, `is None`, static closure config) contributes nothing
     assert all("leaky_step" in f.symbol for f in findings)
-
-
-# ---------------------------------------------------------------------------
-# kernel-contract pass: BlockSpec misdivision
-# ---------------------------------------------------------------------------
-
-
-def test_kernel_pass_flags_blockspec_misdivision():
-    # a head grid the GQA index maps cannot tile: H_pad=6 with KV_pad=4
-    bad = SimpleNamespace(padded_gqa=lambda: (6, 4))
-    findings = blockspec_findings("badfixture", bad)
-    assert [f.rule for f in findings] == ["blockspec"]
-    assert findings[0].symbol == "badfixture/gqa"
-    assert "H %" in findings[0].message
-
-    good = SimpleNamespace(padded_gqa=lambda: (8, 4))
-    assert blockspec_findings("goodfixture", good) == []
 
 
 # ---------------------------------------------------------------------------

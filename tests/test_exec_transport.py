@@ -137,6 +137,17 @@ def test_unpicklable_fn_rejected_at_submit(pool):
         ensure_picklable(nested)
 
 
+def test_device_kinds_refused_on_tpu_host(pool, monkeypatch):
+    """Workers run JAX on their CPU: on a TPU host a train/inference task
+    must be refused at submit instead of quietly running there."""
+    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
+    for kind in SubprocessTransport.DEVICE_KINDS:
+        with pytest.raises(RuntimeError, match="in-process transport"):
+            pool.submit(echo, 1, kind=kind)
+    assert pool.submit(echo, 2, kind="data_engineering").result(
+        timeout=60) == 2
+
+
 def test_unpicklable_argument_names_the_leaf(pool):
     import threading
     with pytest.raises(TypeError, match=r"args\[0\]\['ev'\]"):

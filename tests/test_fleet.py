@@ -137,10 +137,13 @@ class FakeDevice:
 
 
 class FakePilot(Pilot):
-    """Pilot over dummy devices; carve returns a mesh-free communicator."""
+    """Pilot over dummy devices; carve returns a mesh-free communicator
+    whose devices are the real one, where the leased engine is placed."""
 
     def carve(self, devices, mesh_shape=None, mesh_axes=("data",)):
-        return SimpleNamespace(devices=tuple(devices), size=len(devices),
+        real = jax.devices()[0]
+        return SimpleNamespace(devices=(real,) * len(devices),
+                               size=len(devices),
                                backend="fake", build_time_s=0.0,
                                pilot_uid=self.uid)
 
