@@ -13,11 +13,13 @@ TPU it stops before any phase and names the platform it found.
 Phases (one chip):
 
 * kernels -- each serving attention kernel against its ``kernels/ref.py``
-  oracle at tinyllama-1.1b widths, in bf16;
+  oracle at tinyllama-1.1b widths, in bf16, and the contiguous ones again
+  on a cache length their KV spans do not divide;
 * serve -- ``repro.launch.serve.run``: a ``Session`` running the
   ``engine_service`` stage, a full-width tinyllama-1.1b ``ServeEngine`` on
-  paged KV answering 8 requests of 256 prompt and 32 new tokens; the
-  decode and prefill-chunk steps it runs must lower to Pallas kernels;
+  paged KV answering 8 requests of 256 prompt and 32 new tokens; then
+  the decode and prefill-chunk steps that engine jits must lower to
+  Pallas kernels;
 * train -- ``repro.launch.train.run``: the preprocess -> train ->
   postprocess stage graph on full-width xlstm-125m, batch 8 x 1024 tokens.
 
@@ -38,7 +40,6 @@ from __future__ import annotations
 
 import argparse
 import collections
-import inspect
 import json
 import math
 import os
@@ -112,6 +113,11 @@ def kernel_phase(smoke: bool = False, interpret: bool = False) -> dict:
     table = jnp.asarray(table)
     base = jnp.asarray(rng.integers(0, S - T, B), jnp.int32)
     clens = jnp.asarray(rng.integers(1, T + 1, B), jnp.int32)
+    # a cache length no span divides: the last of four spans reads 7 rows
+    # past the cache, which the kernels must mask
+    Sr, span = S - 7, S // 4
+    kr, vr = k[:, :Sr], v[:, :Sr]
+    lens_r, base_r = jnp.minimum(lens, Sr), jnp.minimum(base, Sr - T)
     kw = dict(interpret=interpret)
     cases = {
         "decode_attention": (
@@ -128,6 +134,15 @@ def kernel_phase(smoke: bool = False, interpret: bool = False) -> dict:
                                                base, clens, **kw),
             lambda: ref.prefill_attention_paged_ref(qT, kn, vn, kp, vp, table,
                                                     base, clens)),
+        "decode_attention_span_past_cache": (
+            lambda: da.decode_attention(q1, kr, vr, lens_r, block_k=span,
+                                        **kw),
+            lambda: ref.decode_attention_ref(q1, kr, vr, lens_r)),
+        "prefill_attention_span_past_cache": (
+            lambda: pa.prefill_attention(qT, kn, vn, kr, vr, base_r, clens,
+                                         block_k=span, **kw),
+            lambda: ref.prefill_attention_ref(qT, kn, vn, kr, vr, base_r,
+                                              clens)),
     }
     errors = {}
     for name, (kernel, oracle) in cases.items():
@@ -177,49 +192,20 @@ def serve_phase(smoke: bool = False, fleet: int = 1) -> dict:
 
 
 def serve_step_kernels(smoke: bool = False) -> dict:
-    """Compile the decode step and the prefill-chunk step the engine runs
-    (same config, slots, pages and chunk width as ``serve_phase``); report
-    whether each holds a Pallas kernel (``tpu_custom_call``) on this
-    backend, and its compile seconds."""
-    from repro.common.params import abstract_params
-    from repro.configs import get_config
-    from repro.configs.base import RunConfig
+    """Lower the decode step and the prefill-chunk step of the engine
+    ``serve_phase`` serves with, from that engine's own jitted functions;
+    report whether each holds a Pallas kernel (``tpu_custom_call``) on
+    this backend, and its lowering seconds."""
     from repro.launch import serve
-    from repro.models.lm import lm_paged_cache_specs
-    from repro.serve.engine import ServeEngine
-    from repro.train.state import model_specs
-    from repro.train.step import make_decode_step, make_prefill_chunk_step
 
     args = serve.build_parser().parse_args(serve_argv(smoke))
-    cfg = get_config(SERVE_ARCH, smoke=smoke)
-    slots = min(args.batch, 4)  # launch.serve's default slot count
-    max_len = args.prompt_len + args.gen + 1
-    engine_defaults = inspect.signature(ServeEngine).parameters
-    page = engine_defaults["page_size"].default
-    chunk = engine_defaults["prefill_chunk_tokens"].default
-    max_pages = -(-max_len // page)
-
-    def sds(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    params = abstract_params(model_specs(cfg))
-    cache = abstract_params(lm_paged_cache_specs(cfg, slots * max_pages,
-                                                 page))
-    run_cfg = RunConfig()
-    steps = {
-        "decode": (make_decode_step(cfg, run_cfg),
-                   (params, sds(slots, 1), cache, sds(slots),
-                    sds(slots, max_pages))),
-        "prefill_chunk": (make_prefill_chunk_step(cfg, run_cfg),
-                          (params, sds(slots, chunk), sds(slots), sds(slots),
-                           cache, sds(slots, max_pages))),
-    }
+    engine = serve.build_engine(**serve.engine_args(args))
     out = {}
-    for name, (fn, abstract_args) in steps.items():
+    t0 = time.perf_counter()
+    for name, lowered in engine.lowered_steps().items():
+        out[name] = {"tpu_custom_call": "tpu_custom_call" in lowered.as_text(),
+                     "lower_s": time.perf_counter() - t0}
         t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*abstract_args).compile()
-        out[name] = {"tpu_custom_call": "tpu_custom_call" in compiled.as_text(),
-                     "compile_s": time.perf_counter() - t0}
     return out
 
 
@@ -385,12 +371,12 @@ def main(argv=None) -> int:
                     tolerance=BF16_TOL, seconds=time.perf_counter() - t0)
             check(ops._resolve_decode("auto") == "pallas",
                   "auto dispatch does not pick the Pallas kernels")
+            res = serve_phase()
+            reading("serve", arch=SERVE_ARCH, **_serve_summary(res))
             steps = serve_step_kernels()
             reading("serve-steps", **steps)
             check(all(s["tpu_custom_call"] for s in steps.values()),
                   f"serving steps without Pallas kernels: {steps}")
-            res = serve_phase()
-            reading("serve", arch=SERVE_ARCH, **_serve_summary(res))
             res = train_phase(os.path.join(ckpt, "train"))
             reading("train", arch=TRAIN_ARCH, batch=8, seq=1024,
                     **_train_summary(res))

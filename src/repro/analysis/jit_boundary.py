@@ -41,6 +41,8 @@ from repro.analysis.findings import Finding, rel
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "sharding"}
 _TIME_FUNCS = {"time", "perf_counter", "monotonic", "process_time"}
 _ARRAY_NS = {"jnp", "jax", "lax", "np_like"}
+# jax.sharding queries answered from the trace context (the mesh), not data
+_STATIC_SHARDING_QUERIES = {"get_abstract_mesh"}
 _STATIC_BUILTINS = {"len", "isinstance", "getattr", "hasattr", "type",
                     "range", "sorted", "min", "max", "enumerate", "zip",
                     "tuple", "list", "dict", "set", "str", "repr"}
@@ -535,8 +537,8 @@ class _FunctionAnalyzer:
             if (isinstance(base, ast.Attribute)
                     and isinstance(base.value, ast.Name)
                     and base.value.id == "jax"):
-                # jax.sharding.* returns meshes and shardings: static
-                return base.attr != "sharding"
+                return not (base.attr == "sharding"
+                            and func.attr in _STATIC_SHARDING_QUERIES)
             # method on a traced object (reshape/astype/at...) -> traced
             if self._expr(func.value):
                 return True

@@ -104,6 +104,12 @@ class _Streamer:
             entries = list(self._reqs.values())
         for entry in entries:
             req, sent, finished_sent = entry
+            # read the terminal state BEFORE the token count: the engine
+            # appends a request's last tokens and then finishes it, so a
+            # finish seen first means n covers every token.  The other
+            # order could ship "finish" ahead of the final tokens, and the
+            # parent drops stream frames for a request it has finished.
+            done = req.done()
             n = len(req.tokens)
             try:
                 if n > sent:
@@ -116,7 +122,7 @@ class _Streamer:
                         "first_token_at": req.first_token_at,
                     })
                     entry[1] = n
-                if req.done() and not finished_sent:
+                if done and not finished_sent:
                     self._chan.send({
                         "type": "finish", "task_id": self._task_id,
                         "rid": req.rid, "state": req.state.name,

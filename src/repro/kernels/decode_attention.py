@@ -14,7 +14,9 @@ heads inside the kernel.  Mosaic tiles the last two block dims in
 multiple of (8, 128) or spans the whole array dim; ``(KV, D)`` spans both,
 so the cache-native ``[.., S, KV, D]`` layout needs no transpose.  The
 span length is picked from the shapes (``kv_block``) so the
-double-buffered K and V blocks fit the default scoped VMEM.
+double-buffered K and V blocks fit the default scoped VMEM; when it does
+not divide the cache length, the last span runs past the array and its
+tail rows are masked like any position past ``cache_len``.
 
 Two layouts, one kernel family:
 
@@ -66,16 +68,22 @@ def kv_row_bytes(kv: int, d: int, dtype) -> int:
 
 
 def kv_block(seq: int, block_k: int, kv: int, d: int, dtype) -> int:
-    """KV span per grid program: at most ``block_k``, small enough that
-    K and V double-buffered fit ``KV_BLOCK_VMEM_BYTES``, and dividing
-    ``seq`` (cache rows are power-of-two buckets on the serving path)."""
+    """KV span per grid program: at most ``block_k`` and ``seq``, and
+    small enough that K and V double-buffered fit ``KV_BLOCK_VMEM_BYTES``.
+    The grid takes ``cdiv(seq, span)`` spans."""
     block_k = max(min(block_k, seq), 1)
     row = kv_row_bytes(kv, d, dtype)
     while block_k > 8 and 4 * block_k * row > KV_BLOCK_VMEM_BYTES:
         block_k //= 2
-    while seq % block_k:
-        block_k //= 2
     return block_k
+
+
+def past_seq_to_zero(v, lo, seq: int):
+    """Zero the rows of a ``[bk, D]`` V span at positions ``>= seq``.  A
+    span that runs past the cache reads undefined memory there; masked
+    scores give those rows weight 0, but 0 * NaN is still NaN."""
+    pos = lo + jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], 1), 0)
+    return jnp.where(pos < seq, v, 0.0)
 
 
 def _partial_softmax(q, k, v, kpos, cache_len, scale: float, window: int):
@@ -101,9 +109,10 @@ def _partial_softmax(q, k, v, kpos, cache_len, scale: float, window: int):
 
 
 def _dec_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
-                scale: float, block_k: int, window: int):
+                scale: float, block_k: int, window: int, seq: int = 0):
     """One (row, KV span) program over every KV head: q [1, KV, G, D],
-    k/v [1, block_k, KV, D] -> partials [1, 1, KV, G, {1, 1, D}]."""
+    k/v [1, block_k, KV, D] -> partials [1, 1, KV, G, {1, 1, D}].
+    ``seq`` is the cache length when the last span runs past it, else 0."""
     b = pl.program_id(0)
     sj = pl.program_id(1)
     cache_len = len_ref[b]
@@ -120,6 +129,8 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
             q = q_ref[0, h].astype(jnp.float32)            # [G, D]
             k = k_ref[0, :, h, :].astype(jnp.float32)      # [bk, D]
             v = v_ref[0, :, h, :].astype(jnp.float32)
+            if seq % block_k:
+                v = past_seq_to_zero(v, lo, seq)
             m, l, acc = _partial_softmax(q, k, v, kpos, cache_len, scale,
                                          window)
             m_ref[0, 0, h] = m
@@ -192,9 +203,9 @@ def decode_attention(
     kv_spec = pl.BlockSpec((1, block_k, KV, D),
                            lambda b, sj, lr: (b, sj, 0, 0))
     kernel = functools.partial(_dec_kernel, scale=1.0 / math.sqrt(D),
-                               block_k=block_k, window=window)
+                               block_k=block_k, window=window, seq=S)
     return _split_k_call(kernel, q, cache_len, (k, v), kv_spec,
-                         S // block_k, (), interpret)
+                         pl.cdiv(S, block_k), (), interpret)
 
 
 @functools.partial(

@@ -50,7 +50,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import (KV_BLOCK_VMEM_BYTES, NEG_INF,
-                                            kv_block, kv_row_bytes)
+                                            kv_block, kv_row_bytes,
+                                            past_seq_to_zero)
 
 
 def write_chunk(cache: jnp.ndarray, new: jnp.ndarray, base: jnp.ndarray,
@@ -89,9 +90,10 @@ def write_chunk_paged(pages: jnp.ndarray, block_table: jnp.ndarray,
 
 def _pf_kernel(base_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
                m_scr, l_scr, acc_scr, *,
-               scale: float, block_q: int, block_k: int):
+               scale: float, block_q: int, block_k: int, seq: int = 0):
     """q/o [1, H, bq, D] (head-major), k/v [1, bk, KV, D]; scratch holds
-    each head's running (max, denom, acc) across the KV-span axis."""
+    each head's running (max, denom, acc) across the KV-span axis.
+    ``seq`` is the cache length when the last span runs past it, else 0."""
     b = pl.program_id(0)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -123,6 +125,8 @@ def _pf_kernel(base_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
         for kv in range(KV):  # static head index: a strided VMEM read
             k = k_ref[0, :, kv, :].astype(jnp.float32)    # [bk, D]
             v = v_ref[0, :, kv, :].astype(jnp.float32)
+            if seq % block_k:
+                v = past_seq_to_zero(v, lo, seq)
             for h in range(kv * G, (kv + 1) * G):
                 q = q_ref[0, h].astype(jnp.float32)       # [bq, D]
                 s = jax.lax.dot_general(
@@ -231,8 +235,9 @@ def prefill_attention(
     block_k = kv_block(S, block_k, KV, D, kc.dtype)
     kv_spec = pl.BlockSpec((1, block_k, KV, D),
                            lambda b, qi, kj, br, cr: (b, kj, 0, 0))
-    out = _call(_pf_kernel, q, base, clens, (kc, vc), kv_spec,
-                S // block_k, (), block_q, block_k, interpret)
+    out = _call(functools.partial(_pf_kernel, seq=S), q, base, clens,
+                (kc, vc), kv_spec, pl.cdiv(S, block_k), (), block_q, block_k,
+                interpret)
     return out, kc, vc
 
 

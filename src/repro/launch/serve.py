@@ -124,11 +124,24 @@ def engine_service(ctx, arch: str = "tinyllama-1.1b", smoke: bool = True,
     ``transport="subprocess"`` — a closure over a parent-side engine
     would capture unpicklable device buffers; see README
     "Cross-process execution")."""
-    cfg = get_config(arch, smoke=smoke)
-    engine = ServeEngine(cfg, RunConfig(), max_slots=max_slots,
-                         max_len=max_len, seed=seed,
-                         device=ctx.comm.devices[0])
+    engine = build_engine(arch, smoke, max_slots, max_len, seed,
+                          device=ctx.comm.devices[0])
     return engine.run_service(ctx.control, resume_state=ctx.resume_state)
+
+
+def engine_args(args) -> dict:
+    """``engine_service``'s arguments for a single-engine serve run."""
+    return dict(arch=args.arch, smoke=args.smoke,
+                max_slots=args.slots or min(args.batch, 4),
+                max_len=args.prompt_len + args.gen + 1, seed=0)
+
+
+def build_engine(arch: str, smoke: bool, max_slots: int, max_len: int,
+                 seed: int, device=None) -> ServeEngine:
+    """The engine ``engine_service`` serves with."""
+    return ServeEngine(get_config(arch, smoke=smoke), RunConfig(),
+                       max_slots=max_slots, max_len=max_len, seed=seed,
+                       device=device)
 
 
 def run(args) -> dict:
@@ -139,11 +152,9 @@ def run(args) -> dict:
         if args.disaggregate and args.fleet < 2:
             raise SystemExit("--disaggregate needs --fleet >= 2")
         return run_fleet(args, cfg)
-    slots = args.slots or min(args.batch, 4)
-    max_len = args.prompt_len + args.gen + 1
-    serve_stage = engine_service.bind(
-        arch=args.arch, smoke=args.smoke, max_slots=slots, max_len=max_len,
-        seed=0)
+    eargs = engine_args(args)
+    slots = eargs["max_slots"]
+    serve_stage = engine_service.bind(**eargs)
 
     # the Session's agents OWN their transports: close() drains the worker
     # pool, so the service lease is back before the pilot is recycled —

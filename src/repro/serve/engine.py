@@ -375,6 +375,30 @@ class ServeEngine:
             if self.cache is not None:
                 self.cache = jax.device_put(self.cache, device)
 
+    def lowered_steps(self) -> Dict[str, Any]:
+        """This engine's own jitted decode step and its prefill-chunk step
+        at the full chunk width, lowered on its current params and cache
+        (greedy, every slot active): the programs ``step`` dispatches."""
+        S = self.max_slots
+        T = _bucket(self.prefill_chunk_tokens or self.max_len)
+
+        def ints(*shape):
+            return jnp.zeros(shape, jnp.int32)
+
+        bt = jnp.asarray(self.block_table) if self.paged else None
+        decode_args = (self.params, ints(S), self.cache, ints(S),
+                       jnp.ones(S, bool), jnp.asarray(self.slot_keys),
+                       jnp.asarray(self.slot_temp),
+                       jnp.asarray(self.slot_topk))
+        if self.paged:
+            decode_args += (bt,)
+        return {
+            "decode": self._decode.lower(*decode_args, sampling=False),
+            "prefill_chunk": self._get_prefill(T).lower(
+                self.params, ints(S, T), ints(S), ints(S), self.cache,
+                bt),
+        }
+
     # -- client side ---------------------------------------------------------
 
     def submit(self, request, **kw) -> Request:

@@ -5,11 +5,14 @@ Expected findings (asserted exactly in test_analysis_passes.py):
 * ``time.time()`` under jit (host-sync);
 * ``if y > 0`` — Python branch on a traced value (traced-branch);
 * ``float(y)`` — host cast of a traced value (host-sync);
+* ``if jax.sharding.reshard(...)`` — branch on a traced array that a
+  ``jax.sharding`` call returned (traced-branch);
 * ``leaky_step(x, scale=[...])`` — list display fed to a
   ``static_argnames`` parameter (static-unhashable).
 
 ``clean_step`` exercises the exemptions the pass must honour: shape
-attributes, ``jax.sharding`` queries, ``is None`` tests, closure config.
+attributes, the mesh query ``get_abstract_mesh``, ``is None`` tests,
+closure config.
 """
 import functools
 import time
@@ -25,6 +28,8 @@ def leaky_step(x, scale):
     if y > 0:  # SEEDED VIOLATION: Python branch on a traced value
         y = y + 1.0
     peek = float(y)  # SEEDED VIOLATION: host cast of a traced value
+    if jax.sharding.reshard(y, None) > 0:  # SEEDED VIOLATION: traced
+        y = y - 1.0
     return y, t0, peek
 
 

@@ -51,13 +51,15 @@ def test_jit_pass_reports_exactly_the_seeded_violations():
         ROOT)
     got = sorted((f.rule, f.line) for f in findings)
     assert got == [
-        ("host-sync", 23),          # time.time() under jit
-        ("host-sync", 27),          # float() on a traced value
-        ("static-unhashable", 41),  # list display bound to static arg
-        ("traced-branch", 25),      # if on a traced value
+        ("host-sync", 26),          # time.time() under jit
+        ("host-sync", 30),          # float() on a traced value
+        ("static-unhashable", 46),  # list display bound to static arg
+        ("traced-branch", 28),      # if on a traced value
+        ("traced-branch", 31),      # if on jax.sharding.reshard's result
     ]
     # every finding names the offending jit root; clean_step (shape
-    # attrs, `is None`, static closure config) contributes nothing
+    # attrs, the mesh query, `is None`, static closure config) contributes
+    # nothing
     assert all("leaky_step" in f.symbol for f in findings)
 
 

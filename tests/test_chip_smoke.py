@@ -24,7 +24,8 @@ def test_kernel_phase_matches_oracles(chip_smoke):
     errors = chip_smoke.kernel_phase(smoke=True, interpret=True)
     assert sorted(errors) == sorted([
         "decode_attention", "decode_attention_paged", "prefill_attention",
-        "prefill_attention_paged"])
+        "prefill_attention_paged", "decode_attention_span_past_cache",
+        "prefill_attention_span_past_cache"])
     assert all(e <= chip_smoke.BF16_TOL for e in errors.values())
 
 

@@ -307,6 +307,46 @@ def test_service_stage_streams_match_in_process():
     assert all(len(t) == 6 for t in toks_sub)
 
 
+class _RacingChannel:
+    """Records frames; while the first stream frame is being sent, the
+    'engine' emits the request's last tokens and finishes it."""
+
+    def __init__(self, req):
+        self.req = req
+        self.frames = []
+
+    def send(self, msg):
+        self.frames.append(msg)
+        if msg["type"] == "stream" and not self.req.done():
+            from repro.serve.request import RequestState
+            self.req.tokens.extend([5, 6])
+            self.req.token_times.extend([0.0, 0.0])
+            self.req._finish(RequestState.DONE)
+
+
+def test_streamer_sends_every_token_before_finish():
+    """The parent drops stream frames for a finished request, so the
+    worker must never send "finish" ahead of tokens it has not shipped,
+    even when the engine finishes the request mid-sweep."""
+    from repro.core.exec.worker import _Streamer
+    req = Request(np.arange(1, 9), max_new_tokens=6)
+    req.tokens.extend([1, 2, 3, 4])
+    req.token_times.extend([0.0] * 4)
+    chan = _RacingChannel(req)
+    streamer = _Streamer(chan, task_id=0)
+    streamer.close()  # stop the poll thread: sweeps below are explicit
+    streamer.register(req)
+    streamer.sweep()
+    streamer.sweep()
+    shipped = []
+    for f in chan.frames:
+        if f["type"] == "finish":
+            break
+        shipped.extend(f["tokens"])
+    assert shipped == [1, 2, 3, 4, 5, 6]
+    assert [f["type"] for f in chan.frames] == ["stream", "stream", "finish"]
+
+
 # ---------------------------------------------------------------------------
 # fleet: KV-page handoff round-trips bitwise across the process boundary
 # ---------------------------------------------------------------------------

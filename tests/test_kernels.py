@@ -83,6 +83,7 @@ def _paged_inputs(B, H, KV, S, D, page, seed=0, scramble=True):
     (1, 4, 4, 256, 128, 64),   # MHA
     (4, 16, 1, 1024, 64, 256),  # MQA
     (2, 4, 4, 128, 48, 64),    # MLA-expanded layout (KV == H, qk dim 48)
+    (2, 8, 2, 100, 64, 64),    # last span runs past the cache
 ])
 def test_decode_attention_matches_ref(B, H, KV, S, D, bk):
     q, k, v = _decode_inputs(B, H, KV, S, D)

@@ -41,6 +41,20 @@ def test_prefill_matches_ref_ragged(H, KV):
     np.testing.assert_array_equal(np.asarray(gvc), np.asarray(wvc))
 
 
+def test_prefill_span_past_cache_matches_ref():
+    """S = 40 with 16-row spans: the last span reads 8 rows past the cache,
+    and row 1's queries (positions 30..35) attend into that span."""
+    B, T, H, KV, D, S = 2, 8, 8, 2, 32, 40
+    q, kn, vn, kc, vc = _inputs(B, T, H, KV, D, S, seed=2)
+    base = jnp.array([0, 30], jnp.int32)
+    clens = jnp.array([8, 6], jnp.int32)
+    got, _, _ = pa.prefill_attention(q, kn, vn, kc, vc, base, clens,
+                                     block_q=8, block_k=16, interpret=True)
+    want, _, _ = ref.prefill_attention_ref(q, kn, vn, kc, vc, base, clens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_prefill_padding_rows_exact_zero():
     B, T, H, KV, D, S = 2, 8, 4, 2, 32, 32
     q, kn, vn, kc, vc = _inputs(B, T, H, KV, D, S)

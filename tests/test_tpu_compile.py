@@ -29,6 +29,10 @@ LAYOUTS = {
     # to 48) with the qk head dim (nope 64 + rope 32)
     "minicpm3-4b-mla-expanded": dict(B=8, H=48, KV=48, D=96, S=2048,
                                      page=16, T=64),
+    # a cache length no VMEM-sized span divides (256-token prompts + 32
+    # new tokens + 1): the last span runs past the cache
+    "minicpm3-4b-mla-expanded-s289": dict(B=8, H=48, KV=48, D=96, S=289,
+                                          page=16, T=64),
 }
 KERNELS = ["decode_attention", "decode_attention_paged",
            "prefill_attention", "prefill_attention_paged"]
