@@ -16,6 +16,8 @@ import time
 from typing import (TYPE_CHECKING, Any, Callable, Deque, Dict, List,
                     Optional, Tuple)
 
+import jax
+
 if TYPE_CHECKING:  # annotation only — keeps this module import-light
     from repro.core.resilience.policy import FailurePolicy
 
@@ -70,7 +72,9 @@ class ServiceControl:
 
     def submit_request(self, request: Any) -> Any:
         """Queue a request for the service; returns the request."""
-        with self._cond:
+        with jax.profiler.TraceAnnotation(
+                "service.submit", rid=getattr(request, "rid", "")), \
+                self._cond:
             if self._stop or self._drain:
                 raise RuntimeError(
                     "service is stopping/draining; not accepting requests")

@@ -331,18 +331,17 @@ def attn_apply(
     from repro.distributed.sharding import constrain
 
     cdt = cfg.compute_dtype
-    h = rmsnorm_apply(params["norm"], x, cfg.norm_eps).astype(cdt)
-    src = h if kv_source is None else kv_source.astype(cdt)
-    act_axes = ("act_batch", None, "act_heads", None)
-    q = constrain(jnp.einsum("bsd,dhk->bshk", h, params["wq"].astype(cdt)), act_axes)
-    k = jnp.einsum("bsd,dhk->bshk", src, params["wk"].astype(cdt))
-    v = jnp.einsum("bsd,dhk->bshk", src, params["wv"].astype(cdt))
-    is_self = kv_source is None
-    if is_self and causal:
-        q = _rope_or_mrope(cfg, q, positions)
-        if cache is None:
-            k = _rope_or_mrope(cfg, k, positions)
-        else:
+    with jax.named_scope("attn.qkv"):
+        h = rmsnorm_apply(params["norm"], x, cfg.norm_eps).astype(cdt)
+        src = h if kv_source is None else kv_source.astype(cdt)
+        act_axes = ("act_batch", None, "act_heads", None)
+        q = constrain(jnp.einsum("bsd,dhk->bshk", h,
+                                 params["wq"].astype(cdt)), act_axes)
+        k = jnp.einsum("bsd,dhk->bshk", src, params["wk"].astype(cdt))
+        v = jnp.einsum("bsd,dhk->bshk", src, params["wv"].astype(cdt))
+        is_self = kv_source is None
+        if is_self and causal:
+            q = _rope_or_mrope(cfg, q, positions)
             k = _rope_or_mrope(cfg, k, positions)
     new_cache = None
     if cache is not None and is_self and "k_pages" in cache:
@@ -362,23 +361,27 @@ def attn_apply(
                     "cache-writing prefill through the block tables")
             from repro.kernels import ops
 
-            base = jnp.broadcast_to(
-                jnp.asarray(cache["len"], jnp.int32).reshape(-1),
-                (k.shape[0],))
-            bt = cache["block_table"]
-            o, k_pages, v_pages = ops.prefill_attention_paged(
-                q, k, v, cache["k_pages"], cache["v_pages"], bt, base,
-                chunk_lens, impl=cfg.decode_impl)
-            new_cache = {"k_pages": k_pages, "v_pages": v_pages,
-                         "block_table": bt, "len": base + chunk_lens}
+            # the kernel writes the chunk into the pages itself
+            with jax.named_scope("attn.core"):
+                base = jnp.broadcast_to(
+                    jnp.asarray(cache["len"], jnp.int32).reshape(-1),
+                    (k.shape[0],))
+                bt = cache["block_table"]
+                o, k_pages, v_pages = ops.prefill_attention_paged(
+                    q, k, v, cache["k_pages"], cache["v_pages"], bt, base,
+                    chunk_lens, impl=cfg.decode_impl)
+                new_cache = {"k_pages": k_pages, "v_pages": v_pages,
+                             "block_table": bt, "len": base + chunk_lens}
         else:
-            idx = jnp.asarray(cache["len"])
-            bt = cache["block_table"]
-            k_pages = _paged_append(cache["k_pages"], bt, idx, k[:, 0])
-            v_pages = _paged_append(cache["v_pages"], bt, idx, v[:, 0])
-            new_cache = {"k_pages": k_pages, "v_pages": v_pages,
-                         "block_table": bt, "len": idx + 1}
-            o = _paged_decode_attn(cfg, q, k_pages, v_pages, bt, idx + 1)
+            with jax.named_scope("attn.kv_append"):
+                idx = jnp.asarray(cache["len"])
+                bt = cache["block_table"]
+                k_pages = _paged_append(cache["k_pages"], bt, idx, k[:, 0])
+                v_pages = _paged_append(cache["v_pages"], bt, idx, v[:, 0])
+                new_cache = {"k_pages": k_pages, "v_pages": v_pages,
+                             "block_table": bt, "len": idx + 1}
+            with jax.named_scope("attn.core"):
+                o = _paged_decode_attn(cfg, q, k_pages, v_pages, bt, idx + 1)
     elif cache is not None and is_self:
         S = k.shape[1]
         slots_n = cache["k"].shape[1]
@@ -392,76 +395,93 @@ def attn_apply(
                     "over a warm cache (ring writes need the full prompt)")
             from repro.kernels import ops
 
-            base = jnp.broadcast_to(
-                jnp.asarray(cache["len"], jnp.int32).reshape(-1),
-                (k.shape[0],))
-            o, k_cache, v_cache = ops.prefill_attention(
-                q, k, v, cache["k"], cache["v"], base, chunk_lens,
-                impl=cfg.decode_impl)
-            new_cache = {"k": k_cache, "v": v_cache,
-                         "len": base + chunk_lens}
+            # the kernel writes the chunk into the cache itself
+            with jax.named_scope("attn.core"):
+                base = jnp.broadcast_to(
+                    jnp.asarray(cache["len"], jnp.int32).reshape(-1),
+                    (k.shape[0],))
+                o, k_cache, v_cache = ops.prefill_attention(
+                    q, k, v, cache["k"], cache["v"], base, chunk_lens,
+                    impl=cfg.decode_impl)
+                new_cache = {"k": k_cache, "v": v_cache,
+                             "len": base + chunk_lens}
         elif S > 1:
             # batched prefill: write the whole prompt's K/V into the cache
             # in one shot and run the causal flash pass over the fresh
             # K/V (exact because the cache is statically empty — enforced
             # BEFORE any array conversion, on the raw python length)
             _check_prefill_base(cache["len"])
-            if window and S >= slots_n:
-                # ring cache: only the last `slots_n` positions survive,
-                # each at its position-mod-size slot
-                keep_k = k[:, S - slots_n:]
-                keep_v = v[:, S - slots_n:]
-                ring = (S - slots_n + jnp.arange(slots_n)) % slots_n
-                k_cache = cache["k"].at[:, ring].set(keep_k.astype(cache["k"].dtype))
-                v_cache = cache["v"].at[:, ring].set(keep_v.astype(cache["v"].dtype))
-            else:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-            new_cache = {"k": k_cache, "v": v_cache, "len": S}
-            o = chunked_attention(
-                q, k, v, causal=True, window=window,
-                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-            )
+            with jax.named_scope("attn.kv_append"):
+                if window and S >= slots_n:
+                    # ring cache: only the last `slots_n` positions
+                    # survive, each at its position-mod-size slot
+                    keep_k = k[:, S - slots_n:]
+                    keep_v = v[:, S - slots_n:]
+                    ring = (S - slots_n + jnp.arange(slots_n)) % slots_n
+                    k_cache = cache["k"].at[:, ring].set(
+                        keep_k.astype(cache["k"].dtype))
+                    v_cache = cache["v"].at[:, ring].set(
+                        keep_v.astype(cache["v"].dtype))
+                else:
+                    k_cache = jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
+                    v_cache = jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
+                new_cache = {"k": k_cache, "v": v_cache, "len": S}
+            with jax.named_scope("attn.core"):
+                o = chunked_attention(
+                    q, k, v, causal=True, window=window,
+                    q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                )
         elif jnp.asarray(cache["len"]).ndim == 1:
             # per-slot decode (continuous batching): each row appends at
             # its own length; rows past capacity are dropped, not wrapped
-            idx = jnp.asarray(cache["len"])
-            rows = jnp.arange(k.shape[0])
-            slot = idx % slots_n if window else idx
-            k_cache = cache["k"].at[rows, slot].set(
-                k[:, 0].astype(cache["k"].dtype), mode="drop")
-            v_cache = cache["v"].at[rows, slot].set(
-                v[:, 0].astype(cache["v"].dtype), mode="drop")
-            new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
-            lens = jnp.minimum(idx + 1, slots_n) if window else idx + 1
-            o = _decode_attn(cfg, q, k_cache, v_cache, lens)
+            with jax.named_scope("attn.kv_append"):
+                idx = jnp.asarray(cache["len"])
+                rows = jnp.arange(k.shape[0])
+                slot = idx % slots_n if window else idx
+                k_cache = cache["k"].at[rows, slot].set(
+                    k[:, 0].astype(cache["k"].dtype), mode="drop")
+                v_cache = cache["v"].at[rows, slot].set(
+                    v[:, 0].astype(cache["v"].dtype), mode="drop")
+                new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
+            with jax.named_scope("attn.core"):
+                lens = jnp.minimum(idx + 1, slots_n) if window else idx + 1
+                o = _decode_attn(cfg, q, k_cache, v_cache, lens)
         else:
             # decode: append to cache (ring-buffer for windowed attention)
-            idx = cache["len"]
-            slot = idx % slots_n if window else idx
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
-            new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
-            if window:
-                # ring buffer of exactly `window` slots: all valid once warm
-                o = _decode_attn(cfg, q, k_cache, v_cache,
-                                 jnp.minimum(idx + 1, k_cache.shape[1]))
-            else:
-                o = _decode_attn(cfg, q, k_cache, v_cache, idx + 1)
+            with jax.named_scope("attn.kv_append"):
+                idx = cache["len"]
+                slot = idx % slots_n if window else idx
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+                new_cache = {"k": k_cache, "v": v_cache, "len": idx + 1}
+            with jax.named_scope("attn.core"):
+                if window:
+                    # ring buffer of exactly `window` slots: all valid
+                    # once warm
+                    o = _decode_attn(cfg, q, k_cache, v_cache,
+                                     jnp.minimum(idx + 1, k_cache.shape[1]))
+                else:
+                    o = _decode_attn(cfg, q, k_cache, v_cache, idx + 1)
     elif cache is not None and not is_self:
-        o = _decode_attn(cfg, q, cache["xk"], cache["xv"],
-                         jnp.asarray(cache["xlen"], jnp.int32))
+        with jax.named_scope("attn.core"):
+            o = _decode_attn(cfg, q, cache["xk"], cache["xv"],
+                             jnp.asarray(cache["xlen"], jnp.int32))
         new_cache = cache
     else:
-        o = chunked_attention(
-            q, k, v, causal=causal, window=window,
-            q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-        )
-    y = jnp.einsum("bshk,hkd->bsd", o.astype(cdt), params["wo"].astype(cdt))
-    y = _checkpoint_name(y, "block_out")
-    return x + y.astype(x.dtype), new_cache
+        with jax.named_scope("attn.core"):
+            o = chunked_attention(
+                q, k, v, causal=causal, window=window,
+                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+            )
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", o.astype(cdt),
+                       params["wo"].astype(cdt))
+        y = _checkpoint_name(y, "block_out")
+        return x + y.astype(x.dtype), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -504,21 +524,21 @@ def mla_apply(
     B, S, _ = x.shape
     H, _kv = cfg.padded_gqa()
     nd, rd, vd = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
-    h = rmsnorm_apply(params["norm"], x, cfg.norm_eps).astype(cdt)
-
-    if cfg.q_lora_rank > 0:
-        ql = jnp.einsum("bsd,dr->bsr", h, params["wq_a"].astype(cdt))
-        ql = rmsnorm_apply(params["q_norm"], ql, cfg.norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", ql, params["wq_b"].astype(cdt))
-    else:
-        q = jnp.einsum("bsd,dhk->bshk", h, params["wq"].astype(cdt))
-    q_nope, q_pe = q[..., :nd], q[..., nd:]
-    q_pe = apply_rope(q_pe, positions if positions.ndim == 2 else positions[..., 0], cfg.rope_theta)
-
-    kv = jnp.einsum("bsd,dr->bsr", h, params["wkv_a"].astype(cdt))
-    c_kv, k_pe = kv[..., : cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
-    c_kv = rmsnorm_apply(params["kv_norm"], c_kv, cfg.norm_eps)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions if positions.ndim == 2 else positions[..., 0], cfg.rope_theta)  # [B,S,1,rd]
+    with jax.named_scope("attn.qkv"):
+        pos = positions if positions.ndim == 2 else positions[..., 0]
+        h = rmsnorm_apply(params["norm"], x, cfg.norm_eps).astype(cdt)
+        if cfg.q_lora_rank > 0:
+            ql = jnp.einsum("bsd,dr->bsr", h, params["wq_a"].astype(cdt))
+            ql = rmsnorm_apply(params["q_norm"], ql, cfg.norm_eps)
+            q = jnp.einsum("bsr,rhk->bshk", ql, params["wq_b"].astype(cdt))
+        else:
+            q = jnp.einsum("bsd,dhk->bshk", h, params["wq"].astype(cdt))
+        q_nope, q_pe = q[..., :nd], q[..., nd:]
+        q_pe = apply_rope(q_pe, pos, cfg.rope_theta)
+        kv = jnp.einsum("bsd,dr->bsr", h, params["wkv_a"].astype(cdt))
+        c_kv, k_pe = kv[..., : cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
+        c_kv = rmsnorm_apply(params["kv_norm"], c_kv, cfg.norm_eps)
+        k_pe = apply_rope(k_pe[:, :, None, :], pos, cfg.rope_theta)  # [B,S,1,rd]
 
     new_cache = None
     if cache is not None and S > 1 and chunk_lens is not None:
@@ -530,41 +550,45 @@ def mla_apply(
         from repro.kernels.prefill_attention import (write_chunk,
                                                      write_chunk_paged)
 
-        base = jnp.broadcast_to(
-            jnp.asarray(cache["len"], jnp.int32).reshape(-1), (B,))
-        if "ckv_pages" in cache:
-            bt = cache["block_table"]
-            ckv_pages = write_chunk_paged(
-                cache["ckv_pages"], bt, c_kv, base, chunk_lens)
-            kpe_pages = write_chunk_paged(
-                cache["kpe_pages"], bt, k_pe[:, :, 0, :], base, chunk_lens)
-            new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
-                         "block_table": bt, "len": base + chunk_lens}
-            num_pages, page = ckv_pages.shape[0], ckv_pages.shape[1]
-            btc = jnp.clip(bt, 0, num_pages - 1)
-            mp = bt.shape[1]
-            ckv_c = ckv_pages[btc].reshape(B, mp * page,
-                                           ckv_pages.shape[-1])
-            kpe_c = kpe_pages[btc].reshape(B, mp * page,
-                                           kpe_pages.shape[-1])
-        else:
-            ckv_c = write_chunk(cache["c_kv"], c_kv, base, chunk_lens)
-            kpe_c = write_chunk(cache["k_pe"], k_pe[:, :, 0, :], base,
-                                chunk_lens)
-            new_cache = {"c_kv": ckv_c, "k_pe": kpe_c,
-                         "len": base + chunk_lens}
-        o = _mla_ragged_prefill_attn(cfg, params, q_nope, q_pe, ckv_c,
-                                     kpe_c, base, chunk_lens, cdt)
+        with jax.named_scope("attn.kv_append"):
+            base = jnp.broadcast_to(
+                jnp.asarray(cache["len"], jnp.int32).reshape(-1), (B,))
+            if "ckv_pages" in cache:
+                bt = cache["block_table"]
+                ckv_pages = write_chunk_paged(
+                    cache["ckv_pages"], bt, c_kv, base, chunk_lens)
+                kpe_pages = write_chunk_paged(
+                    cache["kpe_pages"], bt, k_pe[:, :, 0, :], base,
+                    chunk_lens)
+                new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
+                             "block_table": bt, "len": base + chunk_lens}
+            else:
+                ckv_c = write_chunk(cache["c_kv"], c_kv, base, chunk_lens)
+                kpe_c = write_chunk(cache["k_pe"], k_pe[:, :, 0, :], base,
+                                    chunk_lens)
+                new_cache = {"c_kv": ckv_c, "k_pe": kpe_c,
+                             "len": base + chunk_lens}
+        with jax.named_scope("attn.core"):
+            if "ckv_pages" in cache:
+                ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt)
+            o = _mla_ragged_prefill_attn(cfg, params, q_nope, q_pe, ckv_c,
+                                         kpe_c, base, chunk_lens, cdt)
     elif cache is not None and S > 1:
         # batched prefill: write the latent K/V for the whole prompt, then
         # run the full-attention pass over the fresh latents (exact
         # because the cache is statically empty — enforced BEFORE any
         # array conversion, on the raw python length)
         _check_prefill_base(cache["len"])
-        ckv_c = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), 0, axis=1)
-        kpe_c = jax.lax.dynamic_update_slice_in_dim(cache["k_pe"], k_pe[:, :, 0, :].astype(cache["k_pe"].dtype), 0, axis=1)
-        new_cache = {"c_kv": ckv_c, "k_pe": kpe_c, "len": S}
-        o = _mla_full_attention(cfg, params, q_nope, q_pe, c_kv, k_pe, cdt)
+        with jax.named_scope("attn.kv_append"):
+            ckv_c = jax.lax.dynamic_update_slice_in_dim(
+                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), 0, axis=1)
+            kpe_c = jax.lax.dynamic_update_slice_in_dim(
+                cache["k_pe"], k_pe[:, :, 0, :].astype(cache["k_pe"].dtype),
+                0, axis=1)
+            new_cache = {"c_kv": ckv_c, "k_pe": kpe_c, "len": S}
+        with jax.named_scope("attn.core"):
+            o = _mla_full_attention(cfg, params, q_nope, q_pe, c_kv, k_pe,
+                                    cdt)
     elif cache is not None and "ckv_pages" in cache:
         # paged decode: latents append into the shared page pool through
         # the block table, then gather (tiny: rank + rope dims only),
@@ -574,40 +598,61 @@ def mla_apply(
                 "paged prefill is not supported: prefill writes a "
                 "contiguous scratch cache which the engine packs into "
                 "pages (page-aligned chunks)")
-        idx = jnp.asarray(cache["len"])
-        bt = cache["block_table"]
-        ckv_pages = _paged_append(cache["ckv_pages"], bt, idx, c_kv[:, 0])
-        kpe_pages = _paged_append(cache["kpe_pages"], bt, idx,
-                                  k_pe[:, 0, 0, :])
-        new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
-                     "block_table": bt, "len": idx + 1}
-        num_pages, page = ckv_pages.shape[0], ckv_pages.shape[1]
-        btc = jnp.clip(bt, 0, num_pages - 1)
-        mp = bt.shape[1]
-        ckv_c = ckv_pages[btc].reshape(B, mp * page, ckv_pages.shape[-1])
-        kpe_c = kpe_pages[btc].reshape(B, mp * page, kpe_pages.shape[-1])
-        o = _mla_expanded_decode(cfg, params, q_nope, q_pe, ckv_c, kpe_c,
-                                 idx + 1, cdt)
+        with jax.named_scope("attn.kv_append"):
+            idx = jnp.asarray(cache["len"])
+            bt = cache["block_table"]
+            ckv_pages = _paged_append(cache["ckv_pages"], bt, idx,
+                                      c_kv[:, 0])
+            kpe_pages = _paged_append(cache["kpe_pages"], bt, idx,
+                                      k_pe[:, 0, 0, :])
+            new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
+                         "block_table": bt, "len": idx + 1}
+        with jax.named_scope("attn.core"):
+            ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt)
+            o = _mla_expanded_decode(cfg, params, q_nope, q_pe, ckv_c,
+                                     kpe_c, idx + 1, cdt)
     elif cache is not None:
-        idx = jnp.asarray(cache["len"])
-        if idx.ndim == 1:
-            # per-slot decode (continuous batching): row-wise append
-            rows = jnp.arange(B)
-            ckv_c = cache["c_kv"].at[rows, idx].set(
-                c_kv[:, 0].astype(cache["c_kv"].dtype), mode="drop")
-            kpe_c = cache["k_pe"].at[rows, idx].set(
-                k_pe[:, 0, 0, :].astype(cache["k_pe"].dtype), mode="drop")
-        else:
-            ckv_c = jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), idx, axis=1)
-            kpe_c = jax.lax.dynamic_update_slice_in_dim(cache["k_pe"], k_pe[:, :, 0, :].astype(cache["k_pe"].dtype), idx, axis=1)
-        new_cache = {"c_kv": ckv_c, "k_pe": kpe_c, "len": idx + 1}
-        o = _mla_expanded_decode(cfg, params, q_nope, q_pe, ckv_c, kpe_c,
-                                 idx + 1, cdt)
+        with jax.named_scope("attn.kv_append"):
+            idx = jnp.asarray(cache["len"])
+            if idx.ndim == 1:
+                # per-slot decode (continuous batching): row-wise append
+                rows = jnp.arange(B)
+                ckv_c = cache["c_kv"].at[rows, idx].set(
+                    c_kv[:, 0].astype(cache["c_kv"].dtype), mode="drop")
+                kpe_c = cache["k_pe"].at[rows, idx].set(
+                    k_pe[:, 0, 0, :].astype(cache["k_pe"].dtype),
+                    mode="drop")
+            else:
+                ckv_c = jax.lax.dynamic_update_slice_in_dim(
+                    cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), idx,
+                    axis=1)
+                kpe_c = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k_pe"],
+                    k_pe[:, :, 0, :].astype(cache["k_pe"].dtype), idx,
+                    axis=1)
+            new_cache = {"c_kv": ckv_c, "k_pe": kpe_c, "len": idx + 1}
+        with jax.named_scope("attn.core"):
+            o = _mla_expanded_decode(cfg, params, q_nope, q_pe, ckv_c,
+                                     kpe_c, idx + 1, cdt)
     else:
-        o = _mla_full_attention(cfg, params, q_nope, q_pe, c_kv, k_pe, cdt)
-    y = jnp.einsum("bshk,hkd->bsd", o.astype(cdt), params["wo"].astype(cdt))
-    y = _checkpoint_name(y, "block_out")
-    return x + y.astype(x.dtype), new_cache
+        with jax.named_scope("attn.core"):
+            o = _mla_full_attention(cfg, params, q_nope, q_pe, c_kv, k_pe,
+                                    cdt)
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("bshk,hkd->bsd", o.astype(cdt),
+                       params["wo"].astype(cdt))
+        y = _checkpoint_name(y, "block_out")
+        return x + y.astype(x.dtype), new_cache
+
+
+def _mla_gather_pages(ckv_pages, kpe_pages, bt):
+    """Every row's latent and rope-key pages gathered through its block
+    table into contiguous ``[B, max_pages x page, ...]`` caches."""
+    B, mp = bt.shape
+    num_pages, page = ckv_pages.shape[0], ckv_pages.shape[1]
+    btc = jnp.clip(bt, 0, num_pages - 1)
+    return (ckv_pages[btc].reshape(B, mp * page, ckv_pages.shape[-1]),
+            kpe_pages[btc].reshape(B, mp * page, kpe_pages.shape[-1]))
 
 
 def _mla_ragged_prefill_attn(cfg, params, q_nope, q_pe, ckv_c, kpe_c,
@@ -707,6 +752,7 @@ def _act(name: str, x):
     return jax.nn.gelu(x)
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg: ModelConfig, params, x: jnp.ndarray) -> jnp.ndarray:
     cdt = cfg.compute_dtype
     h = rmsnorm_apply(params["norm"], x, cfg.norm_eps).astype(cdt)
@@ -776,6 +822,7 @@ def _positions_in_expert(idx, E, S):
     return pos, onehots
 
 
+@jax.named_scope("moe")
 def moe_apply(cfg: ModelConfig, params, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y, aux_loss)."""
     cdt = cfg.compute_dtype

@@ -329,10 +329,11 @@ def lm_apply(
     per row.
     """
     head, unit, reps, tail = block_pattern(cfg)
-    if inputs.ndim == 2:
-        x = _embed_tokens(cfg, params, inputs)
-    else:
-        x = inputs.astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        if inputs.ndim == 2:
+            x = _embed_tokens(cfg, params, inputs)
+        else:
+            x = inputs.astype(cfg.compute_dtype)
     Bsz, S = x.shape[0], x.shape[1]
     if positions is None:
         if chunk_lens is not None:
@@ -360,81 +361,85 @@ def lm_apply(
                                   chunk_lens)
         return x, (_unpack_cache(tk, nc) if nc is not None else None), aux
 
-    # head
-    for i, (tk, ck) in enumerate(head):
-        c = cache["head_layers"][f"h{i}"] if cache is not None else None
-        x, nc, aux = run_layer(tk, ck, params["head_layers"][f"h{i}"], x, c)
-        aux_total += aux
-        if nc is not None:
-            new_cache["head_layers"][f"h{i}"] = nc
+    # the layer stack: head layers, the scanned unit, tail layers
+    with jax.named_scope("layers"):
+        # head
+        for i, (tk, ck) in enumerate(head):
+            c = cache["head_layers"][f"h{i}"] if cache is not None else None
+            x, nc, aux = run_layer(tk, ck, params["head_layers"][f"h{i}"], x, c)
+            aux_total += aux
+            if nc is not None:
+                new_cache["head_layers"][f"h{i}"] = nc
 
-    # scanned unit
-    if reps > 0:
-        unit_params = params["unit"]
-        unit_cache = cache["unit"] if cache is not None else None
+        # scanned unit
+        if reps > 0:
+            unit_params = params["unit"]
+            unit_cache = cache["unit"] if cache is not None else None
 
-        if unit_cache is None:
+            if unit_cache is None:
 
-            def unit_body(carry, p_i):
-                x, aux_acc = carry
-                # barrier pins the saved-residual dtype: without it XLA:CPU
-                # hoists the first-use f32 convert through the scan's
-                # dynamic-update-slice and stacks the residuals twice
-                # (bf16 + f32) — a 3x memory hit at 4k seq.
-                x = jax.lax.optimization_barrier(x)
-                if cfg.seq_parallel:
-                    # Megatron SP: the saved residual is seq-sharded over
-                    # the model axis (16x smaller stack); GSPMD inserts the
-                    # gather at the first full-sequence consumer
-                    from repro.distributed.sharding import constrain
-                    x = constrain(x, ("act_batch", "act_seq_sp", None))
-                aux_sum = jnp.zeros((), jnp.float32)
-                for j, (tk, ck) in enumerate(unit):
-                    x, _, aux = run_layer(tk, ck, p_i[f"b{j}"], x, None)
-                    aux_sum += aux
-                return (x, aux_acc + aux_sum), None
+                def unit_body(carry, p_i):
+                    x, aux_acc = carry
+                    # barrier pins the saved-residual dtype: without it XLA:CPU
+                    # hoists the first-use f32 convert through the scan's
+                    # dynamic-update-slice and stacks the residuals twice
+                    # (bf16 + f32) — a 3x memory hit at 4k seq.
+                    x = jax.lax.optimization_barrier(x)
+                    if cfg.seq_parallel:
+                        # Megatron SP: the saved residual is seq-sharded over
+                        # the model axis (16x smaller stack); GSPMD inserts the
+                        # gather at the first full-sequence consumer
+                        from repro.distributed.sharding import constrain
+                        x = constrain(x, ("act_batch", "act_seq_sp", None))
+                    aux_sum = jnp.zeros((), jnp.float32)
+                    for j, (tk, ck) in enumerate(unit):
+                        x, _, aux = run_layer(tk, ck, p_i[f"b{j}"], x, None)
+                        aux_sum += aux
+                    return (x, aux_acc + aux_sum), None
 
-            if remat and cfg.remat_policy == "save_block_outputs":
-                body = jax.checkpoint(
-                    unit_body,
-                    policy=jax.checkpoint_policies.save_only_these_names("block_out"),
-                )
-            elif remat:
-                body = jax.checkpoint(unit_body)
+                if remat and cfg.remat_policy == "save_block_outputs":
+                    body = jax.checkpoint(
+                        unit_body,
+                        policy=jax.checkpoint_policies.save_only_these_names("block_out"),
+                    )
+                elif remat:
+                    body = jax.checkpoint(unit_body)
+                else:
+                    body = unit_body
+                (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), unit_params)
             else:
-                body = unit_body
-            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), unit_params)
-        else:
 
-            def unit_body_c(carry, xs):
-                x, aux_acc = carry
-                p_i, c_i = xs
-                nc_i = {}
-                aux_sum = jnp.zeros((), jnp.float32)
-                for j, (tk, ck) in enumerate(unit):
-                    x, nc, aux = run_layer(tk, ck, p_i[f"b{j}"], x, c_i[f"b{j}"])
-                    aux_sum += aux
-                    nc_i[f"b{j}"] = nc
-                return (x, aux_acc + aux_sum), nc_i
+                def unit_body_c(carry, xs):
+                    x, aux_acc = carry
+                    p_i, c_i = xs
+                    nc_i = {}
+                    aux_sum = jnp.zeros((), jnp.float32)
+                    for j, (tk, ck) in enumerate(unit):
+                        x, nc, aux = run_layer(tk, ck, p_i[f"b{j}"], x, c_i[f"b{j}"])
+                        aux_sum += aux
+                        nc_i[f"b{j}"] = nc
+                    return (x, aux_acc + aux_sum), nc_i
 
-            (x, aux_total), scanned_cache = jax.lax.scan(
-                unit_body_c, (x, aux_total), (unit_params, unit_cache)
-            )
-            new_cache["unit"] = scanned_cache
+                (x, aux_total), scanned_cache = jax.lax.scan(
+                    unit_body_c, (x, aux_total), (unit_params, unit_cache)
+                )
+                new_cache["unit"] = scanned_cache
 
-    # tail
-    for i, (tk, ck) in enumerate(tail):
-        c = cache["tail_layers"][f"t{i}"] if cache is not None else None
-        x, nc, aux = run_layer(tk, ck, params["tail_layers"][f"t{i}"], x, c)
-        aux_total += aux
-        if nc is not None:
-            new_cache["tail_layers"][f"t{i}"] = nc
+        # tail
+        for i, (tk, ck) in enumerate(tail):
+            c = cache["tail_layers"][f"t{i}"] if cache is not None else None
+            x, nc, aux = run_layer(tk, ck, params["tail_layers"][f"t{i}"], x, c)
+            aux_total += aux
+            if nc is not None:
+                new_cache["tail_layers"][f"t{i}"] = nc
 
-    if last_only:
-        x = x[:, -1:]
-    x = B.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    head_w = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cfg.compute_dtype)
-    logits = jnp.einsum("bsd,dv->bsv", x.astype(cfg.compute_dtype), head_w)
+    with jax.named_scope("final"):
+        if last_only:
+            x = x[:, -1:]
+        x = B.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        head_w = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cfg.compute_dtype)
+        logits = jnp.einsum("bsd,dv->bsv", x.astype(cfg.compute_dtype),
+                            head_w)
     return logits, (new_cache if cache is not None else None), aux_total

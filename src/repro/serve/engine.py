@@ -75,6 +75,10 @@ from repro.train.step import make_decode_step, make_prefill_chunk_step
 
 _engine_uid = itertools.count()
 
+# the sampler's device operations carry the ``sample`` scope in every
+# program that samples (the decode step and the first-token sampler)
+_sample_tokens = jax.named_scope("sample")(sample_tokens)
+
 
 def _entry_submitted_at(entry) -> float:
     """Submission time of a queue entry (Request or migrated KVHandoff)."""
@@ -190,7 +194,7 @@ class ServeEngine:
         self._prefill_fns: "collections.OrderedDict[int, Any]" = (
             collections.OrderedDict())
         decode = make_decode_step(cfg, self.run_cfg)
-        self._sample = jax.jit(sample_tokens)
+        self._sample = jax.jit(_sample_tokens)
 
         # ``sampling`` is a static flag: an all-greedy batch (the default)
         # keeps the old argmax-only hot path — no full-vocab sort, no
@@ -204,8 +208,8 @@ class ServeEngine:
                 greedy, logits, new_cache = decode(
                     params, tokens[:, None], cache, lengths, block_table)
                 if sampling:
-                    toks, new_keys = sample_tokens(logits[:, -1], keys,
-                                                   temps, topks)
+                    toks, new_keys = _sample_tokens(logits[:, -1], keys,
+                                                    temps, topks)
                 else:
                     toks, new_keys = greedy, keys
                 # inactive slots: their block-table rows are all-sentinel,
@@ -221,8 +225,8 @@ class ServeEngine:
                 greedy, logits, new_cache = decode(params, tokens[:, None],
                                                    cache, lengths)
                 if sampling:
-                    toks, new_keys = sample_tokens(logits[:, -1], keys,
-                                                   temps, topks)
+                    toks, new_keys = _sample_tokens(logits[:, -1], keys,
+                                                    temps, topks)
                 else:
                     toks, new_keys = greedy, keys
 
@@ -258,6 +262,7 @@ class ServeEngine:
         self.slots: List[Optional[Request]] = [None] * max_slots
         self._stats: Dict[str, int] = collections.defaultdict(int)  # guarded-by: _lock
         self._seen_shapes: Dict[str, set] = collections.defaultdict(set)  # guarded-by: _lock
+        self._steps = itertools.count()  # step() calls, for its trace span
         self._init_state()
         self._page_bytes = 0
         self._cache_bytes = _tree_bytes(self.cache)
@@ -664,16 +669,16 @@ class ServeEngine:
             self._prefill_fns.move_to_end(chunk_t)
         return fn
 
-    def _admit(self) -> int:
+    def _admit(self) -> List[Any]:
         """Bind queued requests to free slots (reserving their prompt
         pages); the actual prompt processing happens chunk-by-chunk in
-        ``_prefill_step``.  Returns the number admitted this call."""
+        ``_prefill_step``.  Returns the entries admitted this call."""
         free = [i for i, r in enumerate(self.slots) if r is None]
         with self._lock:
             if not free or not self.queue:
-                return 0
+                return []
             if not self.continuous and len(free) < self.max_slots:
-                return 0  # static batching: wait for the whole batch to end
+                return []  # static batching: wait for the whole batch to end
             batch: List[Any] = []
             reserved = 0
             while self.queue and len(batch) < len(free):
@@ -728,7 +733,7 @@ class ServeEngine:
                     reserved += need
                 batch.append(self.queue.popleft())
         if not batch:
-            return 0
+            return batch
         nb = len(batch)
         now = time.time()
         for j, req in enumerate(batch):
@@ -753,7 +758,7 @@ class ServeEngine:
         with self._lock:
             self._stats["admitted"] += nb
             self._stats["prefill_batches"] += 1
-        return nb
+        return batch
 
     def _import_handoff(self, i: int, hand: KVHandoff,
                         now: float) -> None:
@@ -823,71 +828,77 @@ class ServeEngine:
                 used += take
         if not taking:
             return False
-        # bucket the chunk width so jit retraces stay bounded; rows not
-        # taking tokens this step ride with chunk_lens 0 (inert in the
-        # ragged kernel — no writes, zero output)
-        T = _bucket(max(taking.values()))
-        tokens = np.zeros((self.max_slots, T), np.int32)
-        base = np.zeros(self.max_slots, np.int32)
-        clens = np.zeros(self.max_slots, np.int32)
-        for i, take in taking.items():
-            pos = int(self.prefill_pos[i])
-            tokens[i, :take] = self.slot_prompt[i][pos:pos + take]
-            base[i] = pos
-            clens[i] = take
-        if self.paged:
-            # bucket the table to the PREFILLING rows' own page frontier
-            # (base + chunk), not the global pages-in-use: tying the
-            # prefill shape to other slots' decode growth would recompile
-            # mid-serve whenever an admission lands on a grown pool
-            need = max(-(-(int(base[i]) + take) // self.page_size)
-                       for i, take in taking.items())
-            mb = min(_bucket(need, lo=1), self.max_pages)
-            self._count_retrace("prefill", (T, mb))
-            bt = jnp.asarray(self.block_table[:, :mb])
-        else:
-            self._count_retrace("prefill", (T,))
-            bt = None
-        prefill = self._get_prefill(T)
-        next_tok, last_logits, self.cache = prefill(
-            self.params, jnp.asarray(tokens), jnp.asarray(base),
-            jnp.asarray(clens), self.cache, bt)
-        done = [i for i, take in taking.items()
-                if int(self.prefill_pos[i]) + take
-                >= self.slots[i].prompt_len]
-        for i, take in taking.items():
-            self.prefill_pos[i] += take
-        # first token for rows that just finished their prompt:
-        # per-request sampling params + the slot's seeded stream
-        # (all-greedy rows keep the chunk step's argmax — no sampler call)
-        if done:
-            if any(self.slot_temp[i] > 0 for i in done):
-                first_tok, new_keys = self._sample(
-                    last_logits, jnp.asarray(self.slot_keys),
-                    jnp.asarray(self.slot_temp),
-                    jnp.asarray(self.slot_topk))
-                toks = np.asarray(first_tok)
-                new_keys = np.asarray(new_keys)
-                for i in done:
-                    if self.slot_temp[i] > 0:
-                        self.slot_keys[i] = new_keys[i]
+        with jax.profiler.TraceAnnotation("engine.prefill") as span:
+            # bucket the chunk width so jit retraces stay bounded; rows not
+            # taking tokens this step ride with chunk_lens 0 (inert in the
+            # ragged kernel — no writes, zero output)
+            T = _bucket(max(taking.values()))
+            tokens = np.zeros((self.max_slots, T), np.int32)
+            base = np.zeros(self.max_slots, np.int32)
+            clens = np.zeros(self.max_slots, np.int32)
+            for i, take in taking.items():
+                pos = int(self.prefill_pos[i])
+                tokens[i, :take] = self.slot_prompt[i][pos:pos + take]
+                base[i] = pos
+                clens[i] = take
+            if self.paged:
+                # bucket the table to the PREFILLING rows' own page
+                # frontier (base + chunk), not the global pages-in-use:
+                # tying the prefill shape to other slots' decode growth
+                # would recompile mid-serve whenever an admission lands on
+                # a grown pool
+                need = max(-(-(int(base[i]) + take) // self.page_size)
+                           for i, take in taking.items())
+                mb = min(_bucket(need, lo=1), self.max_pages)
+                self._count_retrace("prefill", (T, mb))
+                bt = jnp.asarray(self.block_table[:, :mb])
+                span.set_metadata(mb=mb)
             else:
-                toks = np.asarray(next_tok)
-            now = time.time()
-            for i in done:
-                req = self.slots[i]
-                self.lengths[i] = req.prompt_len
-                self.prefill_pos[i] = -1
-                self.slot_prompt[i] = None
-                req.first_token_at = now
-                tok = int(toks[i])
-                req.tokens.append(tok)
-                req.token_times.append(now)
-                self.last_tok[i] = tok
-                if self._should_stop(req, tok, int(self.lengths[i])):
-                    self._finish_slot(i, RequestState.DONE)
-                elif self.prefill_only:
-                    self._export_slot(i)
+                self._count_retrace("prefill", (T,))
+                bt = None
+            span.set_metadata(T=T, rows=len(taking), tokens=used)
+            prefill = self._get_prefill(T)
+            next_tok, last_logits, self.cache = prefill(
+                self.params, jnp.asarray(tokens), jnp.asarray(base),
+                jnp.asarray(clens), self.cache, bt)
+            done = [i for i, take in taking.items()
+                    if int(self.prefill_pos[i]) + take
+                    >= self.slots[i].prompt_len]
+            for i, take in taking.items():
+                self.prefill_pos[i] += take
+            # first token for rows that just finished their prompt:
+            # per-request sampling params + the slot's seeded stream
+            # (all-greedy rows keep the chunk step's argmax — no sampler
+            # call)
+            if done:
+                with jax.profiler.TraceAnnotation("engine.prefill.fetch"):
+                    if any(self.slot_temp[i] > 0 for i in done):
+                        first_tok, new_keys = self._sample(
+                            last_logits, jnp.asarray(self.slot_keys),
+                            jnp.asarray(self.slot_temp),
+                            jnp.asarray(self.slot_topk))
+                        toks = np.asarray(first_tok)
+                        new_keys = np.asarray(new_keys)
+                        for i in done:
+                            if self.slot_temp[i] > 0:
+                                self.slot_keys[i] = new_keys[i]
+                    else:
+                        toks = np.asarray(next_tok)
+                now = time.time()
+                for i in done:
+                    req = self.slots[i]
+                    self.lengths[i] = req.prompt_len
+                    self.prefill_pos[i] = -1
+                    self.slot_prompt[i] = None
+                    req.first_token_at = now
+                    tok = int(toks[i])
+                    req.tokens.append(tok)
+                    req.token_times.append(now)
+                    self.last_tok[i] = tok
+                    if self._should_stop(req, tok, int(self.lengths[i])):
+                        self._finish_slot(i, RequestState.DONE)
+                    elif self.prefill_only:
+                        self._export_slot(i)
         with self._lock:
             self._stats["prefill_chunks"] += 1
             self._stats["prefill_tokens"] += used
@@ -897,72 +908,96 @@ class ServeEngine:
         """Admit what fits, spend one bounded prefill chunk, then run one
         fused decode over every slot whose prefill already finished.
         Returns False when there was nothing to do."""
-        inj = rfaults.active()
-        if inj is not None and self.has_work():
-            # chaos site (FaultPlan.crash_engine): only steps with work
-            # count, so the Nth firing is a logical point in the
-            # workload, not a function of idle-spin timing
-            act = inj.fire("engine.step", engine=self.uid)
-            if act is not None and act.get("action") == "crash":
-                raise rfaults.InjectedFault(
-                    f"injected crash at {self.uid} step")
-        progressed = self._admit() > 0
-        progressed = self._prefill_step() or progressed
-        if self.paged:
-            self._ensure_decode_pages()
+        with jax.profiler.TraceAnnotation("engine.step",
+                                          step=next(self._steps)):
+            inj = rfaults.active()
+            if inj is not None and self.has_work():
+                # chaos site (FaultPlan.crash_engine): only steps with work
+                # count, so the Nth firing is a logical point in the
+                # workload, not a function of idle-spin timing
+                act = inj.fire("engine.step", engine=self.uid)
+                if act is not None and act.get("action") == "crash":
+                    raise rfaults.InjectedFault(
+                        f"injected crash at {self.uid} step")
+            with jax.profiler.TraceAnnotation("engine.admit") as span:
+                admitted = self._admit()
+                if admitted:
+                    span.set_metadata(admitted=len(admitted), rids=" ".join(
+                        (r.request if isinstance(r, KVHandoff) else r).rid
+                        for r in admitted))
+            progressed = self._prefill_step() or bool(admitted)
+            if self.paged:
+                self._ensure_decode_pages()
+            return self._decode_step() or progressed
+
+    def _decode_step(self) -> bool:
+        """One fused decode over every slot whose prefill already
+        finished; False when there is none."""
         active = np.array([r is not None and self.prefill_pos[i] < 0
                            for i, r in enumerate(self.slots)])
         if not active.any():
-            return progressed
+            return False
         sampling = bool((self.slot_temp[active] > 0).any())
-        args = (self.params, jnp.asarray(self.last_tok), self.cache,
-                jnp.asarray(self.lengths), jnp.asarray(active),
-                jnp.asarray(self.slot_keys), jnp.asarray(self.slot_temp),
-                jnp.asarray(self.slot_topk))
-        if self.paged:
-            # bucket the block table (and with it the kernel grid) to the
-            # pages actually in use — short sequences never pay max_len
-            mb = min(_bucket(max(len(p) for p in self.slot_pages), lo=1),
-                     self.max_pages)
-            self._count_retrace("decode", (mb, sampling))
-            # mid-prefill slots hold REAL allocated pages but must not
-            # decode: mask their table rows to the sentinel so the decode
-            # step's junk appends drop instead of clobbering their prompt
-            bt_step = self.block_table[:, :mb].copy()
-            bt_step[~active] = self.num_pages
-            args = args + (jnp.asarray(bt_step),)
-        else:
-            self._count_retrace("decode", (self.max_len, sampling))
-        next_tok, new_keys, self.cache = self._decode(*args,
-                                                      sampling=sampling)
-        toks = np.asarray(next_tok)
-        self.slot_keys = np.array(new_keys)  # writable copy
-        self.lengths = self.lengths + active.astype(np.int32)
-        # memory-per-token accounting (what the serving benchmark reports):
-        # paged holds only its allocated pages, contiguous always holds the
-        # full [max_slots, max_len] rows
-        bytes_now = (self.pages_in_use() * self._page_bytes if self.paged
-                     else self._cache_bytes)
-        with self._lock:
-            self._stats["decode_steps"] += 1
-            self._stats["decode_slot_steps"] += int(active.sum())
-            self._stats["kv_bytes_step_sum"] += bytes_now
-            self._stats["kv_tokens_step_sum"] += int(
-                self.lengths[active].sum())
-        generated = 0
-        now = time.time()
-        for i, req in enumerate(self.slots):
-            if req is None or not active[i]:
-                continue
-            tok = int(toks[i])
-            req.tokens.append(tok)
-            req.token_times.append(now)
-            self.last_tok[i] = tok
-            generated += 1
-            if self._should_stop(req, tok, int(self.lengths[i])):
-                self._finish_slot(i, RequestState.DONE)
-        if generated:
-            self._bump("tokens_generated", generated)
+        with jax.profiler.TraceAnnotation(
+                "engine.decode", active=int(active.sum()),
+                sampling=int(sampling)) as span:
+            args = (self.params, jnp.asarray(self.last_tok), self.cache,
+                    jnp.asarray(self.lengths), jnp.asarray(active),
+                    jnp.asarray(self.slot_keys), jnp.asarray(self.slot_temp),
+                    jnp.asarray(self.slot_topk))
+            if self.paged:
+                # bucket the block table (and with it the kernel grid) to
+                # the pages actually in use — short sequences never pay
+                # max_len
+                mb = min(_bucket(max(len(p) for p in self.slot_pages), lo=1),
+                         self.max_pages)
+                span.set_metadata(mb=mb)
+                self._count_retrace("decode", (mb, sampling))
+                # mid-prefill slots hold REAL allocated pages but must not
+                # decode: mask their table rows to the sentinel so the
+                # decode step's junk appends drop instead of clobbering
+                # their prompt
+                bt_step = self.block_table[:, :mb].copy()
+                bt_step[~active] = self.num_pages
+                args = args + (jnp.asarray(bt_step),)
+            else:
+                self._count_retrace("decode", (self.max_len, sampling))
+            next_tok, new_keys, self.cache = self._decode(
+                *args, sampling=sampling)
+            with jax.profiler.TraceAnnotation("engine.decode.fetch"):
+                toks = np.asarray(next_tok)
+                self.slot_keys = np.array(new_keys)  # writable copy
+            self.lengths = self.lengths + active.astype(np.int32)
+            # memory-per-token accounting (what the serving benchmark
+            # reports): paged holds only its allocated pages, contiguous
+            # always holds the full [max_slots, max_len] rows
+            bytes_now = (self.pages_in_use() * self._page_bytes
+                         if self.paged else self._cache_bytes)
+            with self._lock:
+                self._stats["decode_steps"] += 1
+                self._stats["decode_slot_steps"] += int(active.sum())
+                self._stats["kv_bytes_step_sum"] += bytes_now
+                self._stats["kv_tokens_step_sum"] += int(
+                    self.lengths[active].sum())
+            with jax.profiler.TraceAnnotation("engine.emit") as emit:
+                generated = 0
+                finished = []
+                now = time.time()
+                for i, req in enumerate(self.slots):
+                    if req is None or not active[i]:
+                        continue
+                    tok = int(toks[i])
+                    req.tokens.append(tok)
+                    req.token_times.append(now)
+                    self.last_tok[i] = tok
+                    generated += 1
+                    if self._should_stop(req, tok, int(self.lengths[i])):
+                        finished.append(req.rid)
+                        self._finish_slot(i, RequestState.DONE)
+                emit.set_metadata(tokens=generated,
+                                  finished=" ".join(finished))
+            if generated:
+                self._bump("tokens_generated", generated)
         return True
 
     def run_until_drained(self, max_steps: int = 100_000) -> None:
@@ -992,8 +1027,11 @@ class ServeEngine:
             self._init_state()
         while True:
             if control is not None:
-                for req in control.take_requests():
-                    self.submit(req)
+                with jax.profiler.TraceAnnotation("service.take") as span:
+                    taken = control.take_requests()
+                    for req in taken:
+                        self.submit(req)
+                    span.set_metadata(taken=len(taken))
                 if control.stop_requested():
                     # hard stop: sweep any request that raced in after the
                     # take above, then fail everything outstanding so
@@ -1014,7 +1052,8 @@ class ServeEngine:
                 if (control.drain_requested()
                         and control.pending_requests() == 0):
                     break
-                control.wait_for_work(self.idle_wait_s)
+                with jax.profiler.TraceAnnotation("service.wait"):
+                    control.wait_for_work(self.idle_wait_s)
         return self.stats()
 
     # -- reporting -----------------------------------------------------------

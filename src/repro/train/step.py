@@ -225,6 +225,7 @@ def make_prefill_chunk_step(cfg: ModelConfig,
             f"prefill; windowed ring caches need per-row length-aware "
             f"writes)")
 
+    @jax.named_scope("prefill_chunk")
     def chunk_step(params, tokens, base, chunk_lens, cache,
                    block_table=None):
         base = jnp.asarray(base, jnp.int32)
@@ -248,6 +249,7 @@ def make_decode_step(cfg: ModelConfig, run_cfg: Optional[RunConfig] = None):
     holds shared page pools (``lm_paged_cache_specs``) instead of
     contiguous per-row caches."""
 
+    @jax.named_scope("decode_step")
     def decode_step(params, tokens, cache, cache_len, block_table=None):
         if cfg.is_encoder_decoder:
             logits, new_cache = encdec.decode_step(cfg, params, tokens, cache, cache_len)

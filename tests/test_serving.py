@@ -632,3 +632,33 @@ def test_agent_close_stops_running_service(params):
     agent.close(timeout=30)
     assert time.time() - t0 < 30
     assert task.wait(10) and task.state is TaskState.DONE
+
+
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "minicpm3-4b",
+                                  "moonshot-v1-16b-a3b"])
+def test_step_programs_carry_the_model_scopes(arch):
+    """The compiled decode and prefill-chunk programs name each HLO
+    operation's model scope in its ``op_name``: the step, the layer loop,
+    and the blocks' parts (GQA, latent attention, MoE)."""
+    import re
+
+    eng = ServeEngine(get_config(arch, smoke=True), RUN, max_slots=2,
+                      max_len=32)
+    texts = {k: low.compile().as_text()
+             for k, low in eng.lowered_steps().items()}
+    block = "mlp" if arch != "moonshot-v1-16b-a3b" else "moe"
+    paths = {}
+    for kind, step in (("decode", "decode_step"),
+                       ("prefill_chunk", "prefill_chunk")):
+        paths[kind] = [n.split("/") for n in
+                       re.findall(r'op_name="([^"]*)"', texts[kind])]
+        scopes = {p for path in paths[kind] for p in path}
+        assert {step, "embed", "layers", "final", "attn.qkv", "attn.core",
+                "attn.out", block} <= scopes
+        # the layer loop's own operations: in the scan, in no block
+        assert any(path[-2:-1] == ["body"] and "layers" in path
+                   for path in paths[kind])
+    # the decode step appends to the cache in its own scope, inside the
+    # layer loop
+    assert any("layers" in path and "attn.kv_append" in path
+               for path in paths["decode"])
