@@ -70,10 +70,12 @@ def _bench_shape(label, B, H, KV, S, D, block_k, page_size, iters):
     bt = jnp.asarray(
         rng.permutation(num_pages)[:B * max_pages].reshape(B, max_pages),
         jnp.int32)
+    # one layer's pools: the kernels take every layer's stacked, and a
+    # layer index
     kp = jnp.asarray(
-        rng.standard_normal((num_pages, page_size, KV, D)), jnp.float32)
+        rng.standard_normal((1, num_pages, page_size, KV, D)), jnp.float32)
     vp = jnp.asarray(
-        rng.standard_normal((num_pages, page_size, KV, D)), jnp.float32)
+        rng.standard_normal((1, num_pages, page_size, KV, D)), jnp.float32)
 
     # kernel mode: real Pallas on TPU, interpret-mode Pallas elsewhere
     kmode = _resolve_decode("auto")
@@ -85,9 +87,9 @@ def _bench_shape(label, B, H, KV, S, D, block_k, page_size, iters):
     f_vec = jax.jit(lambda q, k, v, l: ops.decode_attention(
         q, k, v, l, impl=kmode, block_k=block_k))
     f_pref = jax.jit(lambda q, kp, vp, bt, l: ops.decode_attention_paged(
-        q, kp, vp, bt, l, impl="ref"))
+        q, kp, vp, bt, l, 0, impl="ref"))
     f_pag = jax.jit(lambda q, kp, vp, bt, l: ops.decode_attention_paged(
-        q, kp, vp, bt, l, impl=kmode))
+        q, kp, vp, bt, l, 0, impl=kmode))
 
     # parity gate before timing: the kernels must match the oracles
     np.testing.assert_allclose(

@@ -105,7 +105,10 @@ def kernel_phase(smoke: bool = False, interpret: bool = False) -> dict:
     q1, qT = normal(B, H, D), normal(B, T, H, D)
     k, v = normal(B, S, KV, D), normal(B, S, KV, D)
     kn, vn = normal(B, T, KV, D), normal(B, T, KV, D)
-    kp, vp = k.reshape(num_pages, page, KV, D), v.reshape(num_pages, page, KV, D)
+    # two layers' pools stacked; the kernels address layer 1 in place
+    kpool = k.reshape(num_pages, page, KV, D)
+    vpool = v.reshape(num_pages, page, KV, D)
+    kp, vp = jnp.stack([vpool, kpool]), jnp.stack([kpool, vpool])
     lens = jnp.asarray(rng.integers(1, S + 1, B), jnp.int32)
     # scrambled pages; row 0 keeps only its live pages, the rest sentinel
     table = rng.permutation(num_pages).reshape(B, mp).astype(np.int32)
@@ -124,16 +127,18 @@ def kernel_phase(smoke: bool = False, interpret: bool = False) -> dict:
             lambda: da.decode_attention(q1, k, v, lens, **kw),
             lambda: ref.decode_attention_ref(q1, k, v, lens)),
         "decode_attention_paged": (
-            lambda: da.decode_attention_paged(q1, kp, vp, table, lens, **kw),
-            lambda: ref.decode_attention_paged_ref(q1, kp, vp, table, lens)),
+            lambda: da.decode_attention_paged(q1, kp, vp, table, lens, 1,
+                                              **kw),
+            lambda: ref.decode_attention_paged_ref(q1, kp, vp, table, lens,
+                                                   1)),
         "prefill_attention": (
             lambda: pa.prefill_attention(qT, kn, vn, k, v, base, clens, **kw),
             lambda: ref.prefill_attention_ref(qT, kn, vn, k, v, base, clens)),
         "prefill_attention_paged": (
             lambda: pa.prefill_attention_paged(qT, kn, vn, kp, vp, table,
-                                               base, clens, **kw),
+                                               base, clens, 1, **kw),
             lambda: ref.prefill_attention_paged_ref(qT, kn, vn, kp, vp, table,
-                                                    base, clens)),
+                                                    base, clens, 1)),
         "decode_attention_span_past_cache": (
             lambda: da.decode_attention(q1, kr, vr, lens_r, block_k=span,
                                         **kw),

@@ -28,12 +28,15 @@ Two layouts, one kernel family:
   granularity inside the kernel, and split-K blocks entirely past a row's
   valid prefix (or entirely before its attention window) are skipped —
   the skipped program writes neutral partials the combine ignores.
-* ``decode_attention_paged`` — a shared page pool ``[num_pages,
-  page_size, KV, D]`` addressed through a per-row block table
-  ``[B, max_pages]``: the block table is a scalar-prefetch operand and the
-  K/V BlockSpec index maps *gather the physical page* for each (row,
-  logical-page) grid step, so one sequence's KV need not be contiguous in
-  memory (vLLM-style PagedAttention, arXiv:2309.06180).  Out-of-range
+* ``decode_attention_paged`` — the page pools of every layer stacked,
+  ``[layers, num_pages, page_size, KV, D]``, addressed by a layer index
+  and a per-row block table ``[B, max_pages]``: both are scalar-prefetch
+  operands and the K/V BlockSpec index maps *gather the physical page* of
+  the layer for each (row, logical-page) grid step, so one sequence's KV
+  need not be contiguous in memory (vLLM-style PagedAttention,
+  arXiv:2309.06180) and the layer's pool is never sliced out of the
+  stack.  The pools' head dim may be wider than the queries' (the model's
+  pools are ``lane_aligned``); the padding lanes are zeros.  Out-of-range
   table entries (free slots use a sentinel) are clamped — they can only
   map to blocks past the row's length, which the mask discards.
 
@@ -65,6 +68,26 @@ def kv_row_bytes(kv: int, d: int, dtype) -> int:
     itemsize = jnp.dtype(dtype).itemsize
     sub = 8 * max(4 // itemsize, 1)
     return -(-kv // sub) * sub * -(-d // 128) * 128 * itemsize
+
+
+def lane_aligned(d: int) -> int:
+    """``d`` rounded up to whole 128-lane rows: the width of a paged pool's
+    head dim.  At that width the pool's default TPU layout is the
+    row-major one the paged kernels read (below it the compiler lays the
+    pool out pages-minor, and every kernel and cache write relayouts the
+    whole pool), and it holds the same bytes as the row-major pool of
+    width ``d``, whose rows pad to 128 lanes anyway."""
+    return -(-d // 128) * 128
+
+
+def pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` with its last axis zero-padded to ``width``: rows written
+    into (or queries read against) a lane-aligned pool.  Zero lanes add
+    nothing to a dot product, and zero V lanes give zero output lanes."""
+    d = x.shape[-1]
+    if d == width:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - d)])
 
 
 def kv_block(seq: int, block_k: int, kv: int, d: int, dtype) -> int:
@@ -213,28 +236,38 @@ def decode_attention(
 )
 def decode_attention_paged(
     q: jnp.ndarray,            # [B, H, D]
-    k_pages: jnp.ndarray,      # [num_pages, page_size, KV, D]  shared pool
+    k_pages: jnp.ndarray,      # [layers, num_pages, page_size, KV, D]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32 (sentinel >= num_pages
     cache_len: jnp.ndarray,    #   marks unallocated logical pages)
+    layer: jnp.ndarray,        # [] int32: the layer whose pages are read
     *,
     window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    num_pages, page_size, KV, D = k_pages.shape
+    """Pools at least as wide as ``q`` (lane-aligned, ``lane_aligned``):
+    ``q`` is zero-padded to their width and the output cut back to its
+    own."""
+    _, num_pages, page_size, KV, Dp = k_pages.shape
+    D = q.shape[-1]
     # clamp sentinel entries in-range: they only ever address positions at
     # or past cache_len, which the in-kernel mask discards
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
 
-    def page_map(b, sj, lr, btr):
-        # gather the physical page through the block table (the paged read)
-        return (btr[b, sj], 0, 0, 0)
+    def page_map(b, sj, lr, layer_ref, btr):
+        # gather the layer's physical page through the block table (the
+        # paged read)
+        return (layer_ref[0], btr[b, sj], 0, 0, 0)
 
-    def kernel(len_ref, bt_ref, *rest):
-        del bt_ref  # consumed by the index maps
+    def kernel(len_ref, layer_ref, bt_ref, *rest):
+        del layer_ref, bt_ref  # consumed by the index maps
         _dec_kernel(len_ref, *rest, scale=1.0 / math.sqrt(D),
                     block_k=page_size, window=window)
 
-    return _split_k_call(kernel, q, cache_len, (k_pages, v_pages),
-                         pl.BlockSpec((1, page_size, KV, D), page_map),
-                         block_table.shape[1], (bt,), interpret)
+    out = _split_k_call(kernel, pad_lanes(q, Dp), cache_len,
+                        (k_pages, v_pages),
+                        pl.BlockSpec((None, 1, page_size, KV, Dp), page_map),
+                        block_table.shape[1],
+                        (jnp.asarray(layer, jnp.int32).reshape(1), bt),
+                        interpret)
+    return out[..., :D]

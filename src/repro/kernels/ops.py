@@ -67,20 +67,24 @@ def decode_attention(q, k, v, cache_len, *, window: int = 0,
     return _ref.decode_attention_ref(q, k, v, cache_len, window=window)
 
 
-def decode_attention_paged(q, k_pages, v_pages, block_table, cache_len, *,
-                           window: int = 0, impl: str = "auto", **kw):
-    """q [B,H,D]; pools [num_pages,page_size,KV,D]; block_table [B,max_pages]
-    int32 (sentinel >= num_pages = unallocated); cache_len [B] -> [B,H,D]."""
+def decode_attention_paged(q, k_pages, v_pages, block_table, cache_len,
+                           layer, *, window: int = 0, impl: str = "auto",
+                           **kw):
+    """q [B,H,D]; pools [layers,num_pages,page_size,KV,Dp >= D] (lane
+    padding past D), read at layer ``layer`` ([] int32); block_table
+    [B,max_pages] int32 (sentinel >= num_pages = unallocated); cache_len
+    [B] -> [B,H,D]."""
     mode = _resolve_decode(impl)
     if mode == "pallas":
         return _dec.decode_attention_paged(
-            q, k_pages, v_pages, block_table, cache_len, window=window, **kw)
+            q, k_pages, v_pages, block_table, cache_len, layer,
+            window=window, **kw)
     if mode == "interpret":
         return _dec.decode_attention_paged(
-            q, k_pages, v_pages, block_table, cache_len, window=window,
-            interpret=True, **kw)
+            q, k_pages, v_pages, block_table, cache_len, layer,
+            window=window, interpret=True, **kw)
     return _ref.decode_attention_paged_ref(
-        q, k_pages, v_pages, block_table, cache_len, window=window)
+        q, k_pages, v_pages, block_table, cache_len, layer, window=window)
 
 
 def prefill_attention(q, k_new, v_new, k_cache, v_cache, base, chunk_lens,
@@ -101,22 +105,25 @@ def prefill_attention(q, k_new, v_new, k_cache, v_cache, base, chunk_lens,
 
 
 def prefill_attention_paged(q, k_new, v_new, k_pages, v_pages, block_table,
-                            base, chunk_lens, *, impl: str = "auto", **kw):
-    """Ragged cache-writing prefill through per-row block tables.
-    q [B,T,H,D]; pools [num_pages,page_size,KV,D]; block_table
-    [B,max_pages] int32 (sentinel >= num_pages = unallocated);
-    base, chunk_lens [] or [B] int32 -> (out, k_pages', v_pages')."""
+                            base, chunk_lens, layer, *, impl: str = "auto",
+                            **kw):
+    """Ragged cache-writing prefill through per-row block tables into
+    layer ``layer`` ([] int32) of the stacked pools.  q [B,T,H,D]; pools
+    [layers,num_pages,page_size,KV,Dp >= D]; block_table [B,max_pages] int32
+    (sentinel >= num_pages = unallocated); base, chunk_lens [] or [B]
+    int32 -> (out, k_pages', v_pages')."""
     mode = _resolve_decode(impl)
     if mode == "pallas":
         return _pf.prefill_attention_paged(
             q, k_new, v_new, k_pages, v_pages, block_table, base,
-            chunk_lens, **kw)
+            chunk_lens, layer, **kw)
     if mode == "interpret":
         return _pf.prefill_attention_paged(
             q, k_new, v_new, k_pages, v_pages, block_table, base,
-            chunk_lens, interpret=True, **kw)
+            chunk_lens, layer, interpret=True, **kw)
     return _ref.prefill_attention_paged_ref(
-        q, k_new, v_new, k_pages, v_pages, block_table, base, chunk_lens)
+        q, k_new, v_new, k_pages, v_pages, block_table, base, chunk_lens,
+        layer)
 
 
 def rmsnorm(x, w, *, eps: float = 1e-5, impl: str = "auto", **kw):

@@ -20,12 +20,13 @@ Two layouts, mirroring the decode kernels:
   as scalar-prefetch operands: blocks past a row's causal frontier or
   past its query chunk are skipped (``pl.when``), the ragged tail block
   is masked at element granularity.
-* ``prefill_attention_paged`` — the shared page pool ``[num_pages,
-  page_size, KV, D]`` addressed through per-row block tables: fresh K/V
-  scatters through the table (sentinel entries drop), and the kernel's
-  K/V BlockSpec index maps gather the physical page per (row,
-  logical-page) grid step — PR 5's paged-read pattern, now on the
-  prefill side.
+* ``prefill_attention_paged`` — the stacked page pools ``[layers,
+  num_pages, page_size, KV, D]`` addressed by a layer index and per-row
+  block tables: fresh K/V scatters into the layer's pages through the
+  table (sentinel entries drop), and the kernel's K/V BlockSpec index
+  maps gather the layer's physical page per (row, logical-page) grid
+  step — the decode kernel's paged read, on the prefill side.  Pools
+  wider than the heads (lane-aligned) take zero-padded rows.
 
 Each grid program is one (batch row, query tile, KV span) and reads the
 span's block over ALL KV heads, ``(1, block_k, KV, D)`` — the layout
@@ -51,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import (KV_BLOCK_VMEM_BYTES, NEG_INF,
                                             kv_block, kv_row_bytes,
-                                            past_seq_to_zero)
+                                            pad_lanes, past_seq_to_zero)
 
 
 def write_chunk(cache: jnp.ndarray, new: jnp.ndarray, base: jnp.ndarray,
@@ -68,11 +69,13 @@ def write_chunk(cache: jnp.ndarray, new: jnp.ndarray, base: jnp.ndarray,
 
 def write_chunk_paged(pages: jnp.ndarray, block_table: jnp.ndarray,
                       new: jnp.ndarray, base: jnp.ndarray,
-                      chunk_lens: jnp.ndarray) -> jnp.ndarray:
-    """Scatter ``new [B, T, ...]`` through per-row block tables into the
-    shared page pool.  Unallocated logical pages hit the sentinel
-    (>= num_pages) and the write drops, as do padding positions."""
-    num_pages, page_size = pages.shape[0], pages.shape[1]
+                      chunk_lens: jnp.ndarray, layer) -> jnp.ndarray:
+    """Scatter ``new [B, T, ...]`` through per-row block tables into layer
+    ``layer`` of the stacked page pools ``[layers, num_pages, page_size,
+    ...]``, in place, zero-padded to the pools' width.  Unallocated
+    logical pages hit the sentinel (>= num_pages) and the write drops, as
+    do padding positions."""
+    num_pages, page_size = pages.shape[1], pages.shape[2]
     B, T = new.shape[0], new.shape[1]
     max_pages = block_table.shape[1]
     j = jnp.arange(T)[None, :]
@@ -85,7 +88,8 @@ def write_chunk_paged(pages: jnp.ndarray, block_table: jnp.ndarray,
         block_table[rows, jnp.minimum(lp, max_pages - 1)],
         num_pages,
     )
-    return pages.at[phys, off].set(new.astype(pages.dtype), mode="drop")
+    return pages.at[layer, phys, off].set(
+        pad_lanes(new, pages.shape[-1]).astype(pages.dtype), mode="drop")
 
 
 def _pf_kernel(base_ref, clen_ref, q_ref, k_ref, v_ref, o_ref,
@@ -173,8 +177,10 @@ def _q_tile(T: int, block_q: int, H: int, D: int, kv_bytes: int) -> int:
 
 
 def _call(kernel, q, base, clens, kv_operands, kv_spec, ns: int,
-          prefetch, block_q: int, block_k: int, interpret: bool):
-    """Shared pallas_call of both layouts: grid (B, q tiles, KV spans)."""
+          prefetch, block_q: int, block_k: int, interpret: bool,
+          scale: float | None = None):
+    """Shared pallas_call of both layouts: grid (B, q tiles, KV spans).
+    ``scale`` defaults to ``1/sqrt(D)`` of ``q``'s own width."""
     B, T, H, D = q.shape
     KV = kv_operands[0].shape[-2]
     kv_bytes = 4 * block_k * kv_row_bytes(KV, D, kv_operands[0].dtype)
@@ -196,8 +202,9 @@ def _call(kernel, q, base, clens, kv_operands, kv_spec, ns: int,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(kernel, scale=1.0 / math.sqrt(D), block_q=block_q,
-                          block_k=block_k),
+        functools.partial(kernel,
+                          scale=scale or 1.0 / math.sqrt(D),
+                          block_q=block_q, block_k=block_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, D), q.dtype),
         interpret=interpret,
@@ -246,37 +253,42 @@ def prefill_attention_paged(
     q: jnp.ndarray,            # [B, T, H, D]
     k_new: jnp.ndarray,        # [B, T, KV, D]
     v_new: jnp.ndarray,
-    k_pages: jnp.ndarray,      # [num_pages, page_size, KV, D]  shared pool
+    k_pages: jnp.ndarray,      # [layers, num_pages, page_size, KV, D]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_pages] int32 (sentinel >= num_pages)
     base: jnp.ndarray,         # [] or [B] int32
     chunk_lens: jnp.ndarray,   # [] or [B] int32
+    layer: jnp.ndarray,        # [] int32: the layer written and read
     *,
     block_q: int = 128,
     interpret: bool = False,
 ):
-    """Returns ``(out [B, T, H, D], k_pages', v_pages')``."""
-    B = q.shape[0]
-    num_pages, page_size, KV, D = k_pages.shape
+    """Returns ``(out [B, T, H, D], k_pages', v_pages')``: the whole
+    stacks, with the chunk written into layer ``layer``.  Pools wider
+    than ``q`` (lane-aligned) read zero-padded queries and rows."""
+    B, D = q.shape[0], q.shape[-1]
+    _, num_pages, page_size, KV, Dp = k_pages.shape
     base = _vec(base, B)
     clens = _vec(chunk_lens, B)
-    kp = write_chunk_paged(k_pages, block_table, k_new, base, clens)
-    vp = write_chunk_paged(v_pages, block_table, v_new, base, clens)
+    kp = write_chunk_paged(k_pages, block_table, k_new, base, clens, layer)
+    vp = write_chunk_paged(v_pages, block_table, v_new, base, clens, layer)
 
     # clamp sentinels in-range: they only address positions at or past a
     # row's live prefix, which the causal mask / block skip discards
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
 
-    def page_map(b, qi, kj, br, cr, btr):
-        return (btr[b, kj], 0, 0, 0)
+    def page_map(b, qi, kj, br, cr, layer_ref, btr):
+        return (layer_ref[0], btr[b, kj], 0, 0, 0)
 
-    def paged_kernel(base_ref, clen_ref, bt_ref, *rest, **kw):
-        # bt_ref is consumed by the BlockSpec index maps above; the body
-        # only needs the per-row base/chunk lengths
-        del bt_ref
+    def paged_kernel(base_ref, clen_ref, layer_ref, bt_ref, *rest, **kw):
+        # layer_ref and bt_ref are consumed by the BlockSpec index maps
+        # above; the body only needs the per-row base/chunk lengths
+        del layer_ref, bt_ref
         _pf_kernel(base_ref, clen_ref, *rest, **kw)
 
-    out = _call(paged_kernel, q, base, clens, (kp, vp),
-                pl.BlockSpec((1, page_size, KV, D), page_map),
-                block_table.shape[1], (bt,), block_q, page_size, interpret)
-    return out, kp, vp
+    out = _call(paged_kernel, pad_lanes(q, Dp), base, clens, (kp, vp),
+                pl.BlockSpec((None, 1, page_size, KV, Dp), page_map),
+                block_table.shape[1],
+                (jnp.asarray(layer, jnp.int32).reshape(1), bt), block_q,
+                page_size, interpret, scale=1.0 / math.sqrt(D))
+    return out[..., :D], kp, vp

@@ -60,16 +60,20 @@ def decode_attention_ref(q, k, v, cache_len, *, window: int = 0) -> jnp.ndarray:
 
 
 def decode_attention_paged_ref(q, k_pages, v_pages, block_table, cache_len,
-                               *, window: int = 0) -> jnp.ndarray:
-    """Paged oracle: gather each row's pages through its block table into a
-    contiguous [B, max_pages*page_size, KV, D] view, then run the masked
-    reference.  Sentinel (out-of-range) table entries are clamped — they
-    only address positions past ``cache_len``, which the mask discards."""
-    num_pages, page_size, KV, D = k_pages.shape
+                               layer, *, window: int = 0) -> jnp.ndarray:
+    """Paged oracle: gather each row's pages of layer ``layer`` of the
+    stacked pools ``[layers, num_pages, page_size, KV, D]`` through its
+    block table into a contiguous [B, max_pages*page_size, KV, D] view,
+    then run the masked reference on ``q``'s width of the pools' head dim
+    (the rest is lane padding).  Sentinel (out-of-range) table entries
+    are clamped — they only address positions past ``cache_len``, which
+    the mask discards."""
+    _, num_pages, page_size, KV, _ = k_pages.shape
+    D = q.shape[-1]
     B, max_pages = block_table.shape
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
-    k = k_pages[bt].reshape(B, max_pages * page_size, KV, D)
-    v = v_pages[bt].reshape(B, max_pages * page_size, KV, D)
+    k = k_pages[layer, bt, ..., :D].reshape(B, max_pages * page_size, KV, D)
+    v = v_pages[layer, bt, ..., :D].reshape(B, max_pages * page_size, KV, D)
     return decode_attention_ref(q, k, v, cache_len, window=window)
 
 
@@ -115,23 +119,25 @@ def prefill_attend_ref(q, kc, vc, base, clens):
 
 
 def prefill_attention_paged_ref(q, k_new, v_new, k_pages, v_pages,
-                                block_table, base, chunk_lens):
-    """Paged prefill oracle: write the chunk through the block tables,
-    gather each row's pages into a contiguous view, and reuse the
-    contiguous oracle's attention (discarding its cache outputs).
-    Returns ``(out [B,T,H,D], k_pages', v_pages')``."""
+                                block_table, base, chunk_lens, layer):
+    """Paged prefill oracle: write the chunk into layer ``layer`` of the
+    stacked pools through the block tables, gather each row's pages of
+    that layer into a contiguous view (``q``'s width of the head dim),
+    and reuse the contiguous oracle's attention.  Returns ``(out
+    [B,T,H,D], k_pages', v_pages')``."""
     from repro.kernels.prefill_attention import write_chunk_paged
 
-    num_pages, page_size, KV, D = k_pages.shape
+    _, num_pages, page_size, KV, _ = k_pages.shape
+    D = q.shape[-1]
     B, max_pages = block_table.shape
     base = jnp.broadcast_to(jnp.asarray(base, jnp.int32).reshape(-1), (B,))
     clens = jnp.broadcast_to(
         jnp.asarray(chunk_lens, jnp.int32).reshape(-1), (B,))
-    kp = write_chunk_paged(k_pages, block_table, k_new, base, clens)
-    vp = write_chunk_paged(v_pages, block_table, v_new, base, clens)
+    kp = write_chunk_paged(k_pages, block_table, k_new, base, clens, layer)
+    vp = write_chunk_paged(v_pages, block_table, v_new, base, clens, layer)
     bt = jnp.clip(block_table.astype(jnp.int32), 0, num_pages - 1)
-    k = kp[bt].reshape(B, max_pages * page_size, KV, D)
-    v = vp[bt].reshape(B, max_pages * page_size, KV, D)
+    k = kp[layer, bt, ..., :D].reshape(B, max_pages * page_size, KV, D)
+    v = vp[layer, bt, ..., :D].reshape(B, max_pages * page_size, KV, D)
     return prefill_attend_ref(q, k, v, base, clens), kp, vp
 
 

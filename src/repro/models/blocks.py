@@ -254,22 +254,28 @@ def _decode_attn(cfg, q, k_cache, v_cache, cache_len, *, window: int = 0):
     return o[:, None].astype(q.dtype)
 
 
-def _paged_decode_attn(cfg, q, k_pages, v_pages, block_table, cache_len):
-    """Paged decode: K/V gathered from a shared page pool through the
-    per-row block table (see kernels/decode_attention.py).  q: [B,1,H,D];
-    pools: [num_pages, page_size, KV, D]; block_table: [B, max_pages]."""
+def _paged_decode_attn(cfg, q, k_pages, v_pages, block_table, cache_len,
+                       layer):
+    """Paged decode: K/V of layer ``layer`` gathered from the stacked page
+    pools through the per-row block table (see
+    kernels/decode_attention.py).  q: [B,1,H,D]; pools: [layers,
+    num_pages, page_size, KV, D]; block_table: [B, max_pages]."""
     from repro.kernels import ops
 
     o = ops.decode_attention_paged(q[:, 0], k_pages, v_pages, block_table,
-                                   cache_len, impl=cfg.decode_impl)
+                                   cache_len, layer, impl=cfg.decode_impl)
     return o[:, None].astype(q.dtype)
 
 
-def _paged_append(pages, block_table, idx, row_vals):
-    """Scatter one new position per row into the shared page pool.
+def _paged_append(pages, block_table, idx, row_vals, layer):
+    """Scatter one new position per row into layer ``layer`` of the stacked
+    page pools ``[layers, num_pages, page_size, ...]``, in place,
+    zero-padded to the pools' width.
     ``idx`` [B] is each row's append position; unallocated / out-of-range
     logical pages hit the sentinel (>= num_pages) and the write drops."""
-    num_pages, page_size = pages.shape[0], pages.shape[1]
+    from repro.kernels.decode_attention import pad_lanes
+
+    num_pages, page_size = pages.shape[1], pages.shape[2]
     max_pages = block_table.shape[1]
     rows = jnp.arange(block_table.shape[0])
     lp = idx // page_size
@@ -279,7 +285,9 @@ def _paged_append(pages, block_table, idx, row_vals):
         block_table[rows, jnp.minimum(lp, max_pages - 1)],
         num_pages,
     )
-    return pages.at[phys, off].set(row_vals.astype(pages.dtype), mode="drop")
+    return pages.at[layer, phys, off].set(
+        pad_lanes(row_vals, pages.shape[-1]).astype(pages.dtype),
+        mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -346,8 +354,11 @@ def attn_apply(
     new_cache = None
     if cache is not None and is_self and "k_pages" in cache:
         # paged decode (continuous batching): each row appends into its
-        # block-table page at its own length, attention gathers K/V
-        # through the table — no contiguous per-slot rows exist
+        # block-table page of this layer's pool at its own length,
+        # attention gathers K/V through the table — no contiguous
+        # per-slot rows exist.  The pools arrive stacked over layers with
+        # this layer's index, and are written and read in place.
+        layer = cache["layer"]
         if window:
             raise NotImplementedError(
                 "windowed attention over a paged cache needs ring-aware "
@@ -369,19 +380,24 @@ def attn_apply(
                 bt = cache["block_table"]
                 o, k_pages, v_pages = ops.prefill_attention_paged(
                     q, k, v, cache["k_pages"], cache["v_pages"], bt, base,
-                    chunk_lens, impl=cfg.decode_impl)
+                    chunk_lens, layer, impl=cfg.decode_impl)
                 new_cache = {"k_pages": k_pages, "v_pages": v_pages,
-                             "block_table": bt, "len": base + chunk_lens}
+                             "layer": layer, "block_table": bt,
+                             "len": base + chunk_lens}
         else:
             with jax.named_scope("attn.kv_append"):
                 idx = jnp.asarray(cache["len"])
                 bt = cache["block_table"]
-                k_pages = _paged_append(cache["k_pages"], bt, idx, k[:, 0])
-                v_pages = _paged_append(cache["v_pages"], bt, idx, v[:, 0])
+                k_pages = _paged_append(cache["k_pages"], bt, idx, k[:, 0],
+                                        layer)
+                v_pages = _paged_append(cache["v_pages"], bt, idx, v[:, 0],
+                                        layer)
                 new_cache = {"k_pages": k_pages, "v_pages": v_pages,
-                             "block_table": bt, "len": idx + 1}
+                             "layer": layer, "block_table": bt,
+                             "len": idx + 1}
             with jax.named_scope("attn.core"):
-                o = _paged_decode_attn(cfg, q, k_pages, v_pages, bt, idx + 1)
+                o = _paged_decode_attn(cfg, q, k_pages, v_pages, bt, idx + 1,
+                                       layer)
     elif cache is not None and is_self:
         S = k.shape[1]
         slots_n = cache["k"].shape[1]
@@ -554,14 +570,15 @@ def mla_apply(
             base = jnp.broadcast_to(
                 jnp.asarray(cache["len"], jnp.int32).reshape(-1), (B,))
             if "ckv_pages" in cache:
-                bt = cache["block_table"]
+                bt, layer = cache["block_table"], cache["layer"]
                 ckv_pages = write_chunk_paged(
-                    cache["ckv_pages"], bt, c_kv, base, chunk_lens)
+                    cache["ckv_pages"], bt, c_kv, base, chunk_lens, layer)
                 kpe_pages = write_chunk_paged(
                     cache["kpe_pages"], bt, k_pe[:, :, 0, :], base,
-                    chunk_lens)
+                    chunk_lens, layer)
                 new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
-                             "block_table": bt, "len": base + chunk_lens}
+                             "layer": layer, "block_table": bt,
+                             "len": base + chunk_lens}
             else:
                 ckv_c = write_chunk(cache["c_kv"], c_kv, base, chunk_lens)
                 kpe_c = write_chunk(cache["k_pe"], k_pe[:, :, 0, :], base,
@@ -570,7 +587,8 @@ def mla_apply(
                              "len": base + chunk_lens}
         with jax.named_scope("attn.core"):
             if "ckv_pages" in cache:
-                ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt)
+                ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt,
+                                                 layer)
             o = _mla_ragged_prefill_attn(cfg, params, q_nope, q_pe, ckv_c,
                                          kpe_c, base, chunk_lens, cdt)
     elif cache is not None and S > 1:
@@ -600,15 +618,15 @@ def mla_apply(
                 "pages (page-aligned chunks)")
         with jax.named_scope("attn.kv_append"):
             idx = jnp.asarray(cache["len"])
-            bt = cache["block_table"]
+            bt, layer = cache["block_table"], cache["layer"]
             ckv_pages = _paged_append(cache["ckv_pages"], bt, idx,
-                                      c_kv[:, 0])
+                                      c_kv[:, 0], layer)
             kpe_pages = _paged_append(cache["kpe_pages"], bt, idx,
-                                      k_pe[:, 0, 0, :])
+                                      k_pe[:, 0, 0, :], layer)
             new_cache = {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
-                         "block_table": bt, "len": idx + 1}
+                         "layer": layer, "block_table": bt, "len": idx + 1}
         with jax.named_scope("attn.core"):
-            ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt)
+            ckv_c, kpe_c = _mla_gather_pages(ckv_pages, kpe_pages, bt, layer)
             o = _mla_expanded_decode(cfg, params, q_nope, q_pe, ckv_c,
                                      kpe_c, idx + 1, cdt)
     elif cache is not None:
@@ -645,14 +663,15 @@ def mla_apply(
         return x + y.astype(x.dtype), new_cache
 
 
-def _mla_gather_pages(ckv_pages, kpe_pages, bt):
-    """Every row's latent and rope-key pages gathered through its block
-    table into contiguous ``[B, max_pages x page, ...]`` caches."""
+def _mla_gather_pages(ckv_pages, kpe_pages, bt, layer):
+    """Every row's latent and rope-key pages of layer ``layer`` gathered
+    from the stacked pools through its block table into contiguous
+    ``[B, max_pages x page, ...]`` caches."""
     B, mp = bt.shape
-    num_pages, page = ckv_pages.shape[0], ckv_pages.shape[1]
+    num_pages, page = ckv_pages.shape[1], ckv_pages.shape[2]
     btc = jnp.clip(bt, 0, num_pages - 1)
-    return (ckv_pages[btc].reshape(B, mp * page, ckv_pages.shape[-1]),
-            kpe_pages[btc].reshape(B, mp * page, kpe_pages.shape[-1]))
+    return (ckv_pages[layer, btc].reshape(B, mp * page, ckv_pages.shape[-1]),
+            kpe_pages[layer, btc].reshape(B, mp * page, kpe_pages.shape[-1]))
 
 
 def _mla_ragged_prefill_attn(cfg, params, q_nope, q_pe, ckv_c, kpe_c,

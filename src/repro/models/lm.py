@@ -6,6 +6,11 @@ Layers are grouped into a repeating *unit* which is ``lax.scan``-ned over
 keeps compile time O(unit) instead of O(num_layers) — essential for the
 512-device dry-runs — while the HLO cost analyzer multiplies while-bodies by
 their trip count so roofline numbers stay honest.
+
+Paged KV pools are the exception to "scanned over": the unit's stacked
+pools ride in the scan's carry and each layer writes and reads its pages
+in place by ``(layer, page)``, so a step never slices a layer's pool out
+of the stack or stacks it back (each would copy the whole pool).
 """
 from __future__ import annotations
 
@@ -141,18 +146,28 @@ def _temporal_cache_specs(kind: str, cfg: ModelConfig, batch: int, max_len: int)
 def _temporal_paged_cache_specs(kind: str, cfg: ModelConfig,
                                 num_pages: int, page_size: int):
     """Paged serving cache: one shared page pool per layer (``[num_pages,
-    page_size, ...]``), addressed through a per-slot block table that
-    lives OUTSIDE the cache tree (it is shared by every layer — all
-    layers append at the same positions).  Attention-family kinds only:
-    recurrent state caches have no sequence axis to page."""
+    page_size, ...]``; the scanned unit's stacked ``[layers, ...]``),
+    addressed through a per-slot block table that lives OUTSIDE the
+    cache tree (it is shared by every layer — all layers append at the
+    same positions).  Attention-family kinds only: recurrent state
+    caches have no sequence axis to page.
+
+    K/V pools are ``lane_aligned`` in their head dim: at that width their
+    default layout is the row-major one the paged kernels read, so every
+    program takes, updates and returns them in one layout.  The MLA
+    latent pools are read by XLA alone and keep their widths."""
     cdt = cfg.compute_dtype
     if kind == "attn":
+        from repro.kernels.decode_attention import lane_aligned
+
         _, KV = cfg.padded_gqa()
         return {
-            "k_pages": Param((num_pages, page_size, KV, cfg.qk_head_dim),
+            "k_pages": Param((num_pages, page_size, KV,
+                              lane_aligned(cfg.qk_head_dim)),
                              ("cache_seq", None, "cache_heads", None),
                              dtype=cdt, init="zeros"),
-            "v_pages": Param((num_pages, page_size, KV, cfg.head_dim),
+            "v_pages": Param((num_pages, page_size, KV,
+                              lane_aligned(cfg.head_dim)),
                              ("cache_seq", None, "cache_heads", None),
                              dtype=cdt, init="zeros"),
         }
@@ -191,19 +206,22 @@ def lm_paged_cache_specs(cfg: ModelConfig, num_pages: int,
     }
 
 
-def _pack_cache(kind: str, raw: Dict, length, block_table=None) -> Dict:
+def _pack_cache(kind: str, raw: Dict, length, block_table=None,
+                layer=None) -> Dict:
     """Join declared cache arrays with the runtime length scalar (and, for
-    paged caches, the shared block table) into the structure the
-    block-apply functions expect."""
+    paged caches, the shared block table and the index of the layer in
+    its stacked pools) into the structure the block-apply functions
+    expect."""
     if kind in ("attn", "local"):
         if "k_pages" in raw:
             return {"k_pages": raw["k_pages"], "v_pages": raw["v_pages"],
-                    "block_table": block_table, "len": length}
+                    "layer": layer, "block_table": block_table,
+                    "len": length}
         return {"k": raw["k"], "v": raw["v"], "len": length}
     if kind == "mla":
         if "ckv_pages" in raw:
             return {"ckv_pages": raw["ckv_pages"],
-                    "kpe_pages": raw["kpe_pages"],
+                    "kpe_pages": raw["kpe_pages"], "layer": layer,
                     "block_table": block_table, "len": length}
         return {"c_kv": raw["c_kv"], "k_pe": raw["k_pe"], "len": length}
     if kind == "rglru":
@@ -354,8 +372,14 @@ def lm_apply(
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {"head_layers": {}, "tail_layers": {}}
 
-    def run_layer(tk, ck, p, x, c):
-        cc = (_pack_cache(tk, c, cache_len, block_table)
+    def run_layer(tk, ck, p, x, c, layer=None):
+        if (c is not None and layer is None
+                and ("k_pages" in c or "ckv_pages" in c)):
+            # an unscanned layer's own pools: a stack of one layer
+            x, nc, aux = run_layer(tk, ck, p, x,
+                                   {k: a[None] for k, a in c.items()}, 0)
+            return x, {k: a[0] for k, a in nc.items()}, aux
+        cc = (_pack_cache(tk, c, cache_len, block_table, layer)
               if c is not None else None)
         x, nc, aux = _layer_apply(cfg, tk, ck, p, x, positions, cc,
                                   chunk_lens)
@@ -407,6 +431,25 @@ def lm_apply(
                 else:
                     body = unit_body
                 (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), unit_params)
+            elif ("k_pages" in unit_cache["b0"]
+                  or "ckv_pages" in unit_cache["b0"]):
+                # the stacked pools ride in the carry and every layer
+                # writes and reads them in place at its own index: XLA
+                # then updates the one buffer the step was given
+                def unit_body_paged(carry, xs):
+                    x, aux_acc, pools = carry
+                    p_i, layer = xs
+                    pools = dict(pools)
+                    aux_sum = jnp.zeros((), jnp.float32)
+                    for j, (tk, ck) in enumerate(unit):
+                        x, pools[f"b{j}"], aux = run_layer(
+                            tk, ck, p_i[f"b{j}"], x, pools[f"b{j}"], layer)
+                        aux_sum += aux
+                    return (x, aux_acc + aux_sum, pools), None
+
+                (x, aux_total, new_cache["unit"]), _ = jax.lax.scan(
+                    unit_body_paged, (x, aux_total, unit_cache),
+                    (unit_params, jnp.arange(reps, dtype=jnp.int32)))
             else:
 
                 def unit_body_c(carry, xs):

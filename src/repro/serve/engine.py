@@ -11,9 +11,12 @@ into the pool, ``_finish_slot`` returns a sequence's pages to the free
 list, and the decode step gathers K/V through the block table inside the
 flash-decode kernel (``kernels/ops.decode_attention_paged``) — the grid
 is bucketed to the pages actually in use, so short sequences never pay
-for ``max_len``.  ``kv_layout="contiguous"`` keeps the PR-3 layout (one
-``[max_slots, max_len]`` row per slot, vector-length kernel) as the
-benchmark baseline.
+for ``max_len``.  The pools are stacked over the scanned layers and
+lane-aligned in their head dim (``models/lm.py``), so every program takes
+and returns them in the one layout the kernels read and each step
+updates them in place.  ``kv_layout="contiguous"`` keeps the older
+layout (one ``[max_slots, max_len]`` row per slot, vector-length kernel)
+as the benchmark baseline.
 
 Admission is *continuous*: whenever a slot is free and a request is
 queued, the request binds to the slot and its pages are reserved;

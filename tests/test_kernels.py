@@ -59,17 +59,18 @@ def _decode_inputs(B, H, KV, S, D, seed=0):
     return q, k, v
 
 
-def _paged_inputs(B, H, KV, S, D, page, seed=0, scramble=True):
-    """Pool + scrambled block table covering [B, S] logical positions,
-    with spare pages left unused and sentinel entries appended."""
+def _paged_inputs(B, H, KV, S, D, page, seed=0, scramble=True, layers=3):
+    """Pools of ``layers`` layers stacked ``[layers, num_pages, page, KV,
+    D]`` (each layer's pages differ) + a scrambled block table covering
+    [B, S] logical positions, with spare pages left unused."""
     rng = np.random.default_rng(seed)
     mp = S // page
     num_pages = B * mp + 3  # spare pages: gather must ignore them
     q = jax.random.normal(jax.random.PRNGKey(seed), (B, H, D))
     kp = jax.random.normal(jax.random.PRNGKey(seed + 1),
-                           (num_pages, page, KV, D))
+                           (layers, num_pages, page, KV, D))
     vp = jax.random.normal(jax.random.PRNGKey(seed + 2),
-                           (num_pages, page, KV, D))
+                           (layers, num_pages, page, KV, D))
     ids = (rng.permutation(num_pages)[:B * mp] if scramble
            else np.arange(B * mp))
     bt = jnp.asarray(ids.reshape(B, mp).astype(np.int32))
@@ -148,13 +149,38 @@ def test_decode_attention_rows_independent():
     (2, 4, 4, 128, 48, 32),    # MLA-expanded layout
 ])
 def test_paged_decode_matches_ref(B, H, KV, S, D, page):
-    q, kp, vp, bt, _ = _paged_inputs(B, H, KV, S, D, page, seed=7)
-    lens = jnp.asarray(np.random.default_rng(B).integers(1, S + 1, B),
-                       jnp.int32)
-    got = da.decode_attention_paged(q, kp, vp, bt, lens, interpret=True)
-    want = ref.decode_attention_paged_ref(q, kp, vp, bt, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
+    """Layer ``l`` of the stacked pools, addressed in place, matches the
+    oracle and the same kernel over that layer's pool sliced out (a
+    one-layer stack), with the rows' unused table tails on the sentinel;
+    over lane-aligned pools it reads the same."""
+    q, kp, vp, bt, num_pages = _paged_inputs(B, H, KV, S, D, page, seed=7)
+    lens = np.random.default_rng(B).integers(1, S + 1, B)
+    bt = np.array(bt)
+    for b in range(B):
+        bt[b, -(-int(lens[b]) // page):] = num_pages
+    bt, lens = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    for layer in (0, 2):
+        got = da.decode_attention_paged(q, kp, vp, bt, lens, layer,
+                                        interpret=True)
+        want = ref.decode_attention_paged_ref(q, kp, vp, bt, lens, layer)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+        sliced = da.decode_attention_paged(
+            q, kp[layer][None], vp[layer][None], bt, lens, 0,
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+    # lane-aligned pools, as the model holds them: what the padding lanes
+    # hold reads as nothing
+    junk = jax.random.normal(jax.random.PRNGKey(5),
+                             kp.shape[:-1] + (da.lane_aligned(D) - D,))
+    kw, vw = (jnp.concatenate([p, junk], -1) for p in (kp, vp))
+    want = ref.decode_attention_paged_ref(q, kp, vp, bt, lens, 2)
+    for got in (da.decode_attention_paged(q, kw, vw, bt, lens, 2,
+                                          interpret=True),
+                ref.decode_attention_paged_ref(q, kw, vw, bt, lens, 2)):
+        assert got.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_paged_decode_matches_contiguous():
@@ -163,14 +189,14 @@ def test_paged_decode_matches_contiguous():
     exactly on, one past, and one before a page boundary."""
     B, H, KV, S, D, page = 3, 8, 2, 256, 32, 64
     q, kp, vp, bt, _ = _paged_inputs(B, H, KV, S, D, page, seed=9)
-    mp = S // page
-    k = kp[bt].reshape(B, S, KV, D)
-    v = vp[bt].reshape(B, S, KV, D)
+    k = kp[1][bt].reshape(B, S, KV, D)
+    v = vp[1][bt].reshape(B, S, KV, D)
     for lens in ([page, 2 * page, 3 * page],        # exactly on boundaries
                  [page + 1, 2 * page - 1, S],       # straddling
                  [1, page // 2, S - 1]):
         cl = jnp.asarray(lens, jnp.int32)
-        got = da.decode_attention_paged(q, kp, vp, bt, cl, interpret=True)
+        got = da.decode_attention_paged(q, kp, vp, bt, cl, 1,
+                                        interpret=True)
         want = da.decode_attention(q, k, v, cl, block_k=page, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)
@@ -183,11 +209,11 @@ def test_paged_decode_sentinel_entries_ignored():
     B, H, KV, S, D, page = 2, 4, 2, 256, 32, 64
     q, kp, vp, bt, num_pages = _paged_inputs(B, H, KV, S, D, page, seed=11)
     lens = jnp.asarray([page, 2 * page], jnp.int32)
-    base = da.decode_attention_paged(q, kp, vp, bt, lens, interpret=True)
+    base = da.decode_attention_paged(q, kp, vp, bt, lens, 2, interpret=True)
     bt_s = np.array(bt)
     bt_s[0, 1:] = num_pages  # rows only keep their live-prefix pages
     bt_s[1, 2:] = num_pages
-    got = da.decode_attention_paged(q, kp, vp, jnp.asarray(bt_s), lens,
+    got = da.decode_attention_paged(q, kp, vp, jnp.asarray(bt_s), lens, 2,
                                     interpret=True)
     np.testing.assert_allclose(np.asarray(base), np.asarray(got),
                                atol=1e-5, rtol=1e-5)
@@ -197,9 +223,9 @@ def test_paged_decode_windowed_matches_ref():
     B, H, KV, S, D, page = 2, 8, 2, 256, 32, 64
     q, kp, vp, bt, _ = _paged_inputs(B, H, KV, S, D, page, seed=13)
     lens = jnp.asarray([S, S // 2 + 3], jnp.int32)
-    got = da.decode_attention_paged(q, kp, vp, bt, lens, window=48,
+    got = da.decode_attention_paged(q, kp, vp, bt, lens, 1, window=48,
                                     interpret=True)
-    want = ref.decode_attention_paged_ref(q, kp, vp, bt, lens, window=48)
+    want = ref.decode_attention_paged_ref(q, kp, vp, bt, lens, 1, window=48)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
 from repro.kernels import prefill_attention as pa
 from repro.kernels import ops, ref
@@ -92,14 +93,18 @@ def test_prefill_chunked_equals_one_shot():
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("H,KV", [(4, 4), (8, 2), (4, 1)])
-def test_prefill_paged_matches_ref(H, KV):
-    B, T, D = 3, 8, 32
+@pytest.mark.parametrize("H,KV,layer", [(4, 4, 0), (8, 2, 1), (4, 1, 2)])
+def test_prefill_paged_matches_ref(H, KV, layer):
+    """A chunk written into and read from layer ``layer`` of the stacked
+    pools in place matches the oracle and the same kernel over that
+    layer's pool sliced out; every other layer's pages stay
+    bit-identical, and lane-aligned pools read and write the same."""
+    B, T, D, L = 3, 8, 32, 3
     page, max_pages, num_pages = 16, 4, 16
     q, kn, vn, _, _ = _inputs(B, T, H, KV, D, 1)
     ks = jax.random.split(jax.random.PRNGKey(7), 2)
-    kp = jax.random.normal(ks[0], (num_pages, page, KV, D))
-    vp = jax.random.normal(ks[1], (num_pages, page, KV, D))
+    kp = jax.random.normal(ks[0], (L, num_pages, page, KV, D))
+    vp = jax.random.normal(ks[1], (L, num_pages, page, KV, D))
     # scrambled physical pages + sentinel (unallocated) tail entries
     bt = jnp.array([[5, 9, 2, num_pages],
                     [0, 7, num_pages, num_pages],
@@ -107,13 +112,41 @@ def test_prefill_paged_matches_ref(H, KV):
     base = jnp.array([0, 5, 13], jnp.int32)
     clens = jnp.array([8, 3, 0], jnp.int32)
     got, gkp, gvp = pa.prefill_attention_paged(
-        q, kn, vn, kp, vp, bt, base, clens, block_q=8, interpret=True)
+        q, kn, vn, kp, vp, bt, base, clens, layer, block_q=8,
+        interpret=True)
     want, wkp, wvp = ref.prefill_attention_paged_ref(
-        q, kn, vn, kp, vp, bt, base, clens)
+        q, kn, vn, kp, vp, bt, base, clens, layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_array_equal(np.asarray(gkp), np.asarray(wkp))
     np.testing.assert_array_equal(np.asarray(gvp), np.asarray(wvp))
+    sliced, skp, svp = pa.prefill_attention_paged(
+        q, kn, vn, kp[layer][None], vp[layer][None], bt, base, clens, 0,
+        block_q=8, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+    np.testing.assert_array_equal(np.asarray(gkp[layer]), np.asarray(skp[0]))
+    np.testing.assert_array_equal(np.asarray(gvp[layer]), np.asarray(svp[0]))
+    assert not np.array_equal(np.asarray(gkp[layer]), np.asarray(kp[layer]))
+    others = np.array([i for i in range(L) if i != layer])
+    np.testing.assert_array_equal(np.asarray(gkp)[others],
+                                  np.asarray(kp)[others])
+    np.testing.assert_array_equal(np.asarray(gvp)[others],
+                                  np.asarray(vp)[others])
+    # lane-aligned pools (junk in the padding lanes): the same output, the
+    # same rows written, their padding lanes zeroed
+    Dp = da.lane_aligned(D)
+    junk = jax.random.normal(jax.random.PRNGKey(9), kp.shape[:-1] + (Dp - D,))
+    kw, vw = (jnp.concatenate([p, junk], -1) for p in (kp, vp))
+    wide, wkp, wvp = pa.prefill_attention_paged(
+        q, kn, vn, kw, vw, bt, base, clens, layer, block_q=8,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for new, narrow, old in ((wkp, gkp, kw), (wvp, gvp, vw)):
+        new, old = np.asarray(new), np.asarray(old)
+        np.testing.assert_array_equal(new[..., :D], np.asarray(narrow))
+        moved = np.any(new != old, axis=(-2, -1))
+        assert moved.any() and not np.any(new[moved][..., D:])
 
 
 def test_prefill_paged_equals_contiguous():
@@ -124,15 +157,15 @@ def test_prefill_paged_equals_contiguous():
     S = page * max_pages
     q, kn, vn, kc, vc = _inputs(B, T, H, KV, D, S)
     bt = jnp.arange(B * max_pages, dtype=jnp.int32).reshape(B, max_pages)
-    kp = kc.reshape(B * max_pages, page, KV, D)
-    vp = vc.reshape(B * max_pages, page, KV, D)
+    kp = kc.reshape(1, B * max_pages, page, KV, D)
+    vp = vc.reshape(1, B * max_pages, page, KV, D)
     base = jnp.array([0, 17], jnp.int32)
     clens = jnp.array([8, 6], jnp.int32)
     oc, kcc, _ = pa.prefill_attention(q, kn, vn, kc, vc, base, clens,
                                       block_q=8, block_k=16,
                                       interpret=True)
     op, kpp, _ = pa.prefill_attention_paged(q, kn, vn, kp, vp, bt, base,
-                                            clens, block_q=8,
+                                            clens, 0, block_q=8,
                                             interpret=True)
     np.testing.assert_array_equal(np.asarray(op), np.asarray(oc))
     np.testing.assert_array_equal(

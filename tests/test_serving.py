@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.layout import Format, Layout
 
 from repro.common.params import init_params, is_param
 from repro.configs import get_config
@@ -350,6 +351,70 @@ def test_paged_checkpoint_restore_roundtrip(params):
     for r, w in zip(reqs, want):
         assert r.state is RequestState.DONE
         assert r.tokens == w, (r.tokens, w)
+
+
+def _pages_minor(tree):
+    """The pools in the pages-minor layout the TPU compiler picks for a
+    pool whose head dim is not lane-aligned (``{1,4,3,2,0}`` for a
+    stacked pool)."""
+    def relayout(a):
+        pages = a.ndim - 4
+        order = tuple(i for i in range(a.ndim) if i != pages) + (pages,)
+        return jax.device_put(a, Format(Layout(order), a.sharding))
+    return jax.tree.map(relayout, tree)
+
+
+def test_paged_pools_keep_one_layout(params):
+    """Every path that hands the paged engine its pools (allocation,
+    checkpoint and restore, a handoff import, ``place``) leaves them
+    lane-aligned and row-major, the layout its steps take and return; a
+    restore fed pages-minor pools hands the steps row-major ones.  The
+    greedy streams through restore and a handoff still equal the
+    contiguous engine's."""
+    rng = np.random.default_rng(13)
+    prompts = _prompts(rng, [5, 9, 12])
+    eng = ServeEngine(CFG32, RUN, max_slots=2, max_len=64, params=params,
+                      kv_layout="paged", page_size=8)
+
+    def one_layout(tree):
+        return all(a.format.layout.major_to_minor == tuple(range(a.ndim))
+                   and a.shape[-1] % 128 == 0
+                   for a in jax.tree.leaves(tree))
+
+    assert one_layout(eng.cache)
+    reqs = [eng.submit(p, max_new_tokens=10) for p in prompts[:2]]
+    for _ in range(4):
+        eng.step()
+    assert one_layout(eng.cache)
+    state = eng.checkpoint()
+    assert one_layout(state["cache"])
+    state["cache"] = _pages_minor(state["cache"])
+    assert not one_layout(state["cache"])
+    eng._release_state()
+    eng.restore(state)
+    assert one_layout(eng.cache)
+    eng.place(jax.devices()[0])
+    assert one_layout(eng.cache)
+
+    pre = ServeEngine(CFG32, RUN, max_slots=2, max_len=64, params=params,
+                      page_size=8, prefill_only=True)
+    migrated = pre.submit(prompts[2], max_new_tokens=10)
+    pre.run_until_drained()
+    [hand] = pre.take_handoffs()
+    assert one_layout(pre.cache)
+    eng.submit(hand)
+    eng.step()  # admit (import) + one decode step
+    assert one_layout(eng.cache)
+    eng.run_until_drained()
+    assert one_layout(eng.cache)
+
+    ref = ServeEngine(CFG32, RUN, max_slots=2, max_len=64, params=params,
+                      kv_layout="contiguous")
+    want = [ref.submit(p, max_new_tokens=10) for p in prompts]
+    ref.run_until_drained()
+    for r, w in zip(reqs + [migrated], want):
+        assert r.state is RequestState.DONE
+        assert r.tokens == w.tokens, (r.tokens, w.tokens)
 
 
 def test_bucket_floor_and_retrace_stats(params):
