@@ -10,8 +10,29 @@
 * a per-layer metric: ``bench/metrics/<name>.py``, a module with
   ``read(run) -> float | None`` (see ``metric_module``).
 
+A family file may also declare what its model adds, for its metrics:
+
+* ``SCOPES``: a tuple of ``jax.named_scope`` names; a traced run counts
+  device time under them beside the harness's own (``spans.SCOPES``);
+* ``token_work(config, keys) -> {name: (operations, bytes)}`` for each
+  output token that attends ``keys`` positions, and ``prompt_work(config,
+  prompt) -> {name: (operations, bytes)}`` for each prompt; the window's
+  sums land in ``run.work[name]``.  Without ``token_work`` the family's
+  ``decode_attention_work`` gives ``run.work["decode_attention"]``.
+
+What a reader's ``run`` holds: ``config``, ``peaks``, ``max_slots``,
+``e2e``; ``counters``, the window's change in every numeric counter the
+engine's ``stats()`` gives; ``work``, ``model_flops`` and the family's
+named ``(operations, bytes)``; and in a traced run ``trace``: ``window_s``,
+``busy_s``, ``ops_s`` (seconds of the operation kinds a reader's ``OPS``
+selects), ``breakdown``, ``spans`` (durations of each program span),
+``idle_by_span``, ``scopes`` (device own time by model scope) and
+``programs`` (device time of each step program's executions).
+
 Adding a cell, a mix, a family or a metric adds files and manifest
-entries; no file of the harness changes.
+entries; no file of the harness changes.  A new cell appends its name to
+the ``workloads`` list of every metric it reports, end-to-end and
+per-layer.
 """
 from __future__ import annotations
 

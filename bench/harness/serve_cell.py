@@ -6,8 +6,10 @@ at the configuration's sizes (page size and prefill chunk at the engine's
 defaults), runs every decode and prefill-chunk shape the mix can reach
 once through the engine's own jitted steps, starts the service and lets
 the engine fill its slots.  The window then measures for ``--seconds``.
-Afterwards the engine's state is freed and the plain reference of the
-configuration's family checks, token by token, a seeded sample of the
+In a traced run the compiled HLO of every warmed step program is taken
+after the window, so the trace's operations can be mapped to their model
+scopes.  Afterwards the engine's state is freed and the plain reference of
+the configuration's family checks, token by token, a seeded sample of the
 requests the window finished (the longest among them) and the unfinished
 request whose context had grown longest: the longest contexts the window
 decoded.
@@ -17,6 +19,7 @@ from __future__ import annotations
 import concurrent.futures
 import dataclasses
 import gc
+import numbers
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -29,6 +32,10 @@ from bench.harness.result import log, percentile
 from bench.harness.trace import WINDOW_SPAN, profiler_options
 
 SAMPLE_REQUESTS = 4   # finished requests the reference replays
+# engine counters every window reports, 0 where the engine never bumped one
+COUNTERS = ("decode_steps", "decode_slot_steps", "prefill_chunks",
+            "prefill_tokens", "retraces", "failed", "completed",
+            "tokens_generated")
 
 
 def _bucket(n: int, lo: int) -> int:
@@ -173,6 +180,15 @@ class ServeCell:
         jax.block_until_ready(self.engine.cache)
         return len(shapes)
 
+    def hlo_texts(self) -> List[str]:
+        """The compiled HLO text of every step program ``warm`` runs,
+        from the programs the engine holds (nothing compiles anew)."""
+        texts = []
+        for shape in self.warm_shapes():
+            fn, args, kw = self._args(*shape)
+            texts.append(fn.lower(*args, **kw).compile().as_text())
+        return texts
+
     # -- window --------------------------------------------------------------
 
     def run(self, seconds: float, trace_dir: Optional[str],
@@ -241,11 +257,8 @@ class ServeCell:
             handle.stop(drain=False, timeout=120)
         if nxt >= len(specs):
             raise RuntimeError("the traffic file ran out of requests")
-        delta = {k: stats1.get(k, 0) - stats0.get(k, 0)
-                 for k in ("decode_steps", "decode_slot_steps",
-                           "prefill_chunks", "prefill_tokens", "retraces",
-                           "failed", "completed", "tokens_generated")}
-        return {"t0": t0, "t1": t1, "served": served, "counters": delta,
+        return {"t0": t0, "t1": t1, "served": served,
+                "counters": counter_deltas(stats0, stats1),
                 "max_slots": self.slots}
 
     # -- after the window ----------------------------------------------------
@@ -273,6 +286,7 @@ def measure(cell, seed: int, seconds: float, trace_dir: Optional[str],
     short = [s for s in done
              if len(s.request.tokens) != s.spec.max_new_tokens]
     chosen = sample(done, seed) + longest_open(win)
+    hlo = run.hlo_texts() if trace_dir else None
     run.free_engine()
     t_ref = time.time()
     gaps = logit_gaps(cell, run.params, chosen)
@@ -295,7 +309,21 @@ def measure(cell, seed: int, seconds: float, trace_dir: Optional[str],
             "setup_s": win["t0"] - process_start,
             "e2e": e2e_metrics(win),
             "work": window_work(win, cell.config, run.family),
-            "counters": win["counters"], "max_slots": win["max_slots"]}
+            "counters": win["counters"], "max_slots": win["max_slots"],
+            "hlo": hlo}
+
+
+def counter_deltas(before: Dict[str, Any], after: Dict[str, Any]
+                   ) -> Dict[str, Any]:
+    """The window's change in every numeric engine counter both
+    snapshots hold (``ServeEngine.stats()``), and in ``COUNTERS``."""
+    def number(x) -> bool:
+        return isinstance(x, numbers.Real) and not isinstance(x, bool)
+
+    delta = {k: after.get(k, 0) - before.get(k, 0) for k in COUNTERS}
+    delta.update({k: after[k] - before[k] for k in after
+                  if k in before and number(after[k]) and number(before[k])})
+    return delta
 
 
 def e2e_metrics(win: Dict) -> Dict[str, float]:
@@ -316,24 +344,40 @@ def e2e_metrics(win: Dict) -> Dict[str, float]:
 
 def window_work(win: Dict, config: Dict[str, Any], family) -> Dict[str, Any]:
     """Work the window's tokens needed, counted from the request stream:
-    each output token attends its prompt and the tokens before it."""
+    each output token attends its prompt and the tokens before it.
+    ``model_flops`` from the family's ``prompt_flops`` and
+    ``token_flops``; beside it ``(operations, bytes)`` under each name
+    the family's ``prompt_work(config, prompt)`` gives for a prompt whose
+    first token falls in the window and its ``token_work(config, keys)``
+    for every later output token in it (without ``token_work``, its
+    ``decode_attention_work`` as ``decode_attention``)."""
     t0, t1 = win["t0"], win["t1"]
+    token_work = getattr(family, "token_work", None) or (
+        lambda c, keys: {
+            "decode_attention": family.decode_attention_work(c, keys)})
+    prompt_work = getattr(family, "prompt_work", None)
     flops = 0.0
-    dec = [0.0, 0.0]
+    work: Dict[str, Any] = {}
+
+    def add(items: Dict[str, Any]) -> None:
+        for name, (f, b) in items.items():
+            f0, b0 = work.get(name, (0.0, 0.0))
+            work[name] = (f0 + f, b0 + b)
+
     for s in win["served"]:
         r = s.request
         P = r.prompt_len
         if r.first_token_at is not None and t0 <= r.first_token_at <= t1:
             flops += family.prompt_flops(config, P)
+            if prompt_work is not None:
+                add(prompt_work(config, P))
         for j, t in enumerate(r.token_times):
             if j == 0 or not (t0 <= t <= t1):
                 continue
             keys = P + j
             flops += family.token_flops(config, keys, logits=True)
-            f, b = family.decode_attention_work(config, keys)
-            dec[0] += f
-            dec[1] += b
-    return {"model_flops": flops, "decode_attention": tuple(dec)}
+            add(token_work(config, keys))
+    return {"model_flops": flops, **work}
 
 
 def finished(win: Dict) -> List[Served]:

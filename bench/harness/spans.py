@@ -11,8 +11,9 @@ each host event's thread and arguments and each device operation's
 * idle by span: every idle nanosecond of the device in the window put
   down to the innermost program span open on the engine's thread (the
   thread that carries ``engine.step``), ``none`` where none is open;
-* device own time by innermost model scope, ``(no scope)`` for an
-  operation whose ``op_name`` holds none;
+* device own time by innermost model scope (``SCOPES``, and those a
+  model family declares), ``(no scope)`` for an operation whose
+  ``op_name`` holds none;
 * the device time of each execution of each step program;
 * the per-layer numbers read from them (``METRICS``).
 
@@ -21,8 +22,10 @@ event is named after its HLO instruction (``%fusion.12 = bf16[..] ...``)
 and lies inside its program's execution on the device's ``XLA Modules``
 line (``jit__step(<fingerprint>)``).  ``op_names_from_hlo`` maps
 (program, instruction, result shape) to the ``op_name`` in the compiled
-HLO text of the programs the window ran, which the caller takes after
-the window.
+HLO text of the programs the window ran, which the cell module takes
+after the window.  ``window_summary`` merges these tables with
+``trace.summarize``'s numbers of the same profile: one read of a traced
+window gives every reader what it needs.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ import re
 import statistics
 import sys
 from typing import (Callable, Dict, Iterable, List, Mapping, Optional,
-                    Tuple)
+                    Sequence, Tuple)
 
 from bench.harness import trace as tr
 
@@ -40,13 +43,13 @@ PROGRAM_SPANS = ("engine.", "service.")
 ENGINE_SPAN = "engine.step"
 NO_SPAN = "none"
 # the model scopes of the serve path (``train/step.py``, ``models/lm.py``,
-# ``models/blocks.py``, the engine's sampler), outermost first
+# ``models/blocks.py``, the engine's sampler), outermost first; a family
+# file adds its own in ``SCOPES`` (``cell_scopes``)
 SCOPES = ("decode_step", "prefill_chunk", "sample", "embed", "layers",
           "final", "attn.qkv", "attn.kv_append", "attn.core", "attn.out",
           "mlp", "moe")
 NO_SCOPE = "(no scope)"
-# where ``trace.read_profile`` looks: each TPU's operations and the host
-DEVICE_PLANE, OPS_LINE, HOST_PLANE = "/device:TPU:", "XLA Ops", "/host:CPU"
+# each TPU's line of its step programs' executions
 MODULE_LINE = "XLA Modules"
 # the prefill-chunk step's program: ``train/step.py``'s ``chunk_step``
 CHUNK_PROGRAM = "jit_chunk_step"
@@ -79,11 +82,14 @@ class SpanProfile(tr.Profile):
 OpNames = Mapping[Tuple[str, ...], str]
 
 
-def read_spans(path: str, op_names: Optional[OpNames] = None
-               ) -> SpanProfile:
-    """``trace.read_profile``'s events, each host event with its thread
-    and arguments, each device operation with its ``op_name`` (looked up
-    in ``op_names``), and each device's program executions."""
+def read_spans(path: str, op_names: Optional[OpNames] = None,
+               device_prefix: str = tr.DEVICE_PLANE,
+               device_lines: Sequence[str] = (tr.OPS_LINE,),
+               host_prefix: str = tr.HOST_PLANE) -> SpanProfile:
+    """``trace.read_profile``'s events, by its rules and in its order,
+    each host event with its thread and arguments, each device operation
+    with its ``op_name`` (looked up in ``op_names``), and each device's
+    program executions."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -91,20 +97,22 @@ def read_spans(path: str, op_names: Optional[OpNames] = None
     programs: Dict[str, List[tr.Event]] = {}
     host: List[tr.Event] = []
     for plane in data.planes:
-        is_dev = plane.name.startswith(DEVICE_PLANE)
-        is_host = plane.name.startswith(HOST_PLANE)
+        is_dev = plane.name.startswith(device_prefix)
+        is_host = plane.name.startswith(host_prefix)
         if not (is_dev or is_host):
             continue
-        ops: List[Tuple[object, float, float]] = []
+        ops: Optional[List[Tuple[object, float, float]]] = None
         for i, line in enumerate(plane.lines):
             if is_dev and line.name == MODULE_LINE:
                 programs[plane.name] = sorted(
                     (tr.Event(_FINGERPRINT.sub("", e.name), e.start_ns,
                               e.start_ns + e.duration_ns)
                      for e in line.events), key=lambda e: e.start_ns)
-            dev_line = is_dev and line.name == OPS_LINE
+            dev_line = is_dev and tr.ops_line(line.name, device_lines)
             if not (dev_line or is_host):
                 continue
+            if dev_line and ops is None:
+                ops = []
             for e in line.events:
                 end = e.start_ns + e.duration_ns
                 if dev_line:
@@ -115,7 +123,7 @@ def read_spans(path: str, op_names: Optional[OpNames] = None
                         PROGRAM_SPANS) else {}
                     host.append(HostEvent(e.name, e.start_ns, end,
                                           thread=i, args=args))
-        if ops:
+        if ops is not None:
             devices[plane.name] = _named_ops(
                 ops, programs.get(plane.name, []), op_names or {})
     host.sort(key=lambda e: e.start_ns)
@@ -162,10 +170,16 @@ def op_names_from_hlo(texts: Iterable[str]) -> Dict[Tuple[str, ...], str]:
     return out
 
 
-def scope_of(op_name: str) -> str:
+def cell_scopes(family) -> Tuple[str, ...]:
+    """``SCOPES`` and the scopes a model family's file declares in its
+    own ``SCOPES``."""
+    return SCOPES + tuple(getattr(family, "SCOPES", ()))
+
+
+def scope_of(op_name: str, scopes: Sequence[str] = SCOPES) -> str:
     """The innermost model scope named in an ``op_name`` path."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return NO_SCOPE
 
@@ -249,14 +263,14 @@ def idle_by_span(profile: SpanProfile, lo: float, hi: float
     return dict(out)
 
 
-def scope_seconds(profile: SpanProfile, lo: float, hi: float
-                  ) -> Dict[str, float]:
+def scope_seconds(profile: SpanProfile, lo: float, hi: float,
+                  scopes: Sequence[str] = SCOPES) -> Dict[str, float]:
     """Device own time (seconds, averaged over devices) by each
-    operation's innermost model scope."""
+    operation's innermost model scope among ``scopes``."""
     total: Dict[str, float] = collections.defaultdict(float)
     k = max(len(profile.devices), 1)
     for evs in profile.devices.values():
-        named = [tr.Event(scope_of(e.op_name), e.start_ns, e.end_ns)
+        named = [tr.Event(scope_of(e.op_name, scopes), e.start_ns, e.end_ns)
                  for e in evs]
         for scope, sec in tr.self_seconds(named, lo, hi).items():
             total[scope] += sec / k
@@ -275,18 +289,26 @@ def program_seconds(profile: SpanProfile, lo: float, hi: float
     return dict(out)
 
 
-def summarize(profile: SpanProfile) -> Dict:
-    """The window, busy seconds, and the span, idle, scope and program
-    tables."""
+def summarize(profile: SpanProfile, scopes: Sequence[str] = SCOPES
+              ) -> Dict:
+    """The window, and the span, idle, scope and program tables."""
     lo, hi = profile.window()
     return {
         "window_s": (hi - lo) / 1e9,
-        "busy_s": tr.busy_seconds(profile, lo, hi),
         "spans": span_seconds(profile, lo, hi),
         "idle_by_span": idle_by_span(profile, lo, hi),
-        "scopes": scope_seconds(profile, lo, hi),
+        "scopes": scope_seconds(profile, lo, hi, scopes),
         "programs": program_seconds(profile, lo, hi),
     }
+
+
+def window_summary(profile: SpanProfile,
+                   ops: Optional[Dict[str, Callable[[str], bool]]] = None,
+                   scopes: Sequence[str] = SCOPES) -> Dict:
+    """What a traced run's readers get as ``run.trace``: the window,
+    busy seconds, selected operation seconds and breakdown of
+    ``trace.summarize``, and ``summarize``'s tables, from one profile."""
+    return {**summarize(profile, scopes), **tr.summarize(profile, ops)}
 
 
 def log_tables(summary: Dict) -> None:

@@ -22,6 +22,8 @@ import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench.window"
+# where ``read_profile`` looks: each TPU's operations and the host
+DEVICE_PLANE, OPS_LINE, HOST_PLANE = "/device:TPU:", "XLA Ops", "/host:CPU"
 # an HLO instruction's numeric suffix (``fusion.12``) names one instance
 # of an operation in one program; the breakdown adds instances together
 _SUFFIX = re.compile(r"(\.\d+)+$")
@@ -65,9 +67,15 @@ def find_profile(log_dir: str) -> str:
     return max(paths, key=os.path.getmtime)
 
 
-def read_profile(path: str, device_prefix: str = "/device:TPU:",
-                 device_lines: Sequence[str] = ("XLA Ops",),
-                 host_prefix: str = "/host:CPU") -> Profile:
+def ops_line(name: str, device_lines: Sequence[str]) -> bool:
+    """Whether a device plane's line holds its operations: the one rule of
+    every reader of a profile."""
+    return name.startswith(tuple(device_lines))
+
+
+def read_profile(path: str, device_prefix: str = DEVICE_PLANE,
+                 device_lines: Sequence[str] = (OPS_LINE,),
+                 host_prefix: str = HOST_PLANE) -> Profile:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -79,7 +87,7 @@ def read_profile(path: str, device_prefix: str = "/device:TPU:",
         if not (is_dev or is_host):
             continue
         for line in plane.lines:
-            dev_line = is_dev and line.name.startswith(tuple(device_lines))
+            dev_line = is_dev and ops_line(line.name, device_lines)
             if not (dev_line or is_host):
                 continue
             evs = [Event(e.name, e.start_ns, e.start_ns + e.duration_ns)

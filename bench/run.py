@@ -8,7 +8,8 @@ building the program, compiling every shape the window uses, filling the
 engine) runs first; then the window measures for ``--seconds``.  With
 ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-window and the program's counters.  Afterwards the plain reference checks
+window (one read: operations, program spans and executions, model
+scopes) and the program's counters.  Afterwards the plain reference checks
 what the window produced, and the last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 (``breakdown`` when traced) and ``checks``, each compared number beside its
@@ -77,7 +78,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     result's parts."""
     import importlib
 
-    from bench.harness import result, trace as tr
+    from bench.harness import result, spans, trace as tr
     from bench.harness.manifest import metric_module
     from bench.harness.peaks import peaks_for
 
@@ -100,9 +101,14 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
                    for m in cell.per_layer}
         ops = {k: re.compile(pattern).search for r in readers.values()
                for k, pattern in getattr(r, "OPS", {}).items()}
-        summary = tr.summarize(tr.read_profile(tr.find_profile(trace_dir)),
-                               ops)
+        # the trace names each operation's HLO instruction, not its
+        # op_name: the compiled programs map one to the other
+        profile = spans.read_spans(tr.find_profile(trace_dir),
+                                   spans.op_names_from_hlo(out.pop("hlo")))
+        summary = spans.window_summary(profile, ops,
+                                       spans.cell_scopes(cell.family()))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        out["trace"] = summary
         run = types.SimpleNamespace(
             config=cell.config, peaks=peaks, trace=summary,
             counters=out["counters"], work=out["work"],

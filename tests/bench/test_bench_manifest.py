@@ -10,6 +10,8 @@ import subprocess
 import sys
 from contextlib import redirect_stdout, redirect_stderr
 
+import pytest
+
 from bench.harness import manifest, result
 from bench_smoke import REPO, make_smoke_root
 
@@ -107,8 +109,27 @@ def kv_bytes_per_token(c):
 
 token_flops = _base.token_flops
 prompt_flops = _base.prompt_flops
-decode_attention_work = _base.decode_attention_work
+SCOPES = ("toy.scope",)
+
+
+def token_work(c, keys):
+    return {"decode_attention": _base.decode_attention_work(c, keys),
+            "toy.latent": (1.0, 0.0)}
+
+
+def prompt_work(c, prompt):
+    return {"toy.latent": (0.0, 1.0)}
 """
+# the toy's per-layer metrics: device time in its scope, its counter over
+# the decode steps, and its named work (output tokens, prompts)
+TOY_METRICS = {
+    "toy_scope_ns.serve_tok_s":
+        "return 1e9 * run.trace['scopes'].get('toy.scope', 0.0) or None",
+    "toy_routed.serve_tok_s":
+        "return run.counters['toy_routed'] / run.counters['decode_steps']",
+    "toy_latent.serve_tok_s":
+        "return run.work['toy.latent'][0] + 1e-6 * run.work['toy.latent'][1]",
+}
 TOY_REF = """
 import pathlib
 from bench.harness.manifest import load_module
@@ -121,13 +142,45 @@ def forward(params, tokens, config, quant=None):
 """
 
 
-def test_a_new_model_family_is_found_by_model_type(tmp_path, run_script,
-                                                   cpu_peaks):
-    """A configuration of a new ``model_type`` brings its family and its
-    reference as two new files; the serving cell runs it end to end
-    through them, with no harness file changed."""
-    import jax
+def _harness_files():
+    return {p: p.read_bytes() for p in sorted((REPO / "bench").rglob("*.py"))
+            if "__pycache__" not in p.parts}
 
+
+def test_a_new_model_family_is_found_by_model_type(tmp_path, run_script,
+                                                   cpu_peaks, monkeypatch):
+    """A configuration of a new ``model_type`` brings its family and its
+    reference as two new files, and per-layer metrics as files of their
+    own; the serving cell runs it end to end through them, traced, with
+    no harness file changed: the family's scope, its work item and an
+    engine counter it adds each reach the metrics' ``read(run)``."""
+    import jax
+    from repro.serve import ServeEngine
+
+    from bench.harness import spans
+
+    before = _harness_files()
+    stats = ServeEngine.stats
+
+    def with_counter(self):      # a counter the new family's model adds
+        out = stats(self)
+        out["toy_routed"] = 3 * out.get("decode_steps", 0)
+        return out
+
+    read_spans = spans.read_spans
+
+    def with_device(path, op_names=None, **where):
+        # the CPU has no device plane: lend the trace one operation in the
+        # family's scope, 20 ns into the window
+        prof = read_spans(path, op_names, **where)
+        lo, _ = prof.window()
+        prof.devices["/device:TPU:0"] = [spans.DeviceOp(
+            "%fusion.1 = f32[2]{0} fusion(", lo + 10, lo + 30,
+            op_name="jit(_step)/decode_step/layers/toy.scope/dot")]
+        return prof
+
+    monkeypatch.setattr(ServeEngine, "stats", with_counter)
+    monkeypatch.setattr(spans, "read_spans", with_device)
     root = make_smoke_root(tmp_path)
     cfg = json.loads((root / "bench/configs/phi3-mini-3.8b-d8.json")
                      .read_text())
@@ -145,14 +198,31 @@ def test_a_new_model_family_is_found_by_model_type(tmp_path, run_script,
     for m in man["end_to_end"] + man["per_layer"]:
         if "workloads" in m:
             m["workloads"].append("toy.offline-decode")
+    for name, body in TOY_METRICS.items():
+        (root / "bench/metrics" / f"{name}.py").write_text(
+            f"def read(run):\n    {body}\n")
+        man["per_layer"].append({
+            "name": name, "unit": "1", "better": "higher",
+            "source": "program_counter", "layer": "toy",
+            "moves": "serve_tok_s", "workloads": ["toy.offline-decode"]})
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     cell = manifest.find_cell("toy.offline-decode", root)
-    out = run_script.measure(cell, 2**32 + 77, 1.0, False, jax.devices())
+    out = run_script.measure(cell, 2**32 + 77, 1.0, True, jax.devices())
     assert out["correct"] is True
     assert {"program_config", "kv_bytes_per_token"} <= set(
         cell.family().CALLS)
     assert cell.reference().CALLS and set(cell.reference().CALLS) == {
         int(cell.traffic["max_len"])}
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert got["toy_scope_ns.serve_tok_s"] == pytest.approx(20.0)
+    assert got["toy_routed.serve_tok_s"] == 3.0
+    tokens, prompts = out["work"]["toy.latent"]
+    assert tokens >= 1 and prompts >= 1
+    assert got["toy_latent.serve_tok_s"] == tokens + 1e-6 * prompts
+    # the toy's work item rides beside the decode attention it still gives
+    assert "decode_attn_roofline.serve_tok_s" not in got   # no kernel here
+    assert out["work"]["decode_attention"][0] > 0
+    assert _harness_files() == before
 
 
 def test_last_line_holds_the_contract_keys_and_checks_last():

@@ -39,10 +39,15 @@ def test_traced_run_reports_per_layer_metrics(run_script, cpu_peaks, cell):
     out = run_script.measure(cell, SEED + 1, 2.0, True, jax.devices())
     assert out["correct"] is True
     got = out["metrics"]
-    # the CPU has no device plane: no kernel time, so no roofline share
-    assert "decode_attn_roofline.serve_tok_s" not in got
+    # the CPU has no device plane: no kernel time, so no roofline share,
+    # no program executions and no device idle to put down to a span
+    for name in ("decode_attn_roofline.serve_tok_s",
+                 "prefill_chunk_ms.itl_p95_ms", "idle_host.serve_tok_s"):
+        assert name not in got
     assert 0 < got["slot_occupancy.serve_tok_s"]["value"] <= 100
     assert 0 < got["mfu.serve_tok_s"]["value"] < 100
+    assert got["decode_step_ms.serve_tok_s"]["value"] > 0
+    assert got["decode_step_ms.serve_tok_s"]["unit"] == "ms"
     assert {"busy_s", "window_s"} <= set(out["device"])
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
 
@@ -80,6 +85,16 @@ def test_altered_token_makes_the_run_not_correct(run_script, cpu_peaks,
     assert out["correct"] is False
     assert out["checks"]["logit_gap"]["value"] > \
         out["checks"]["logit_gap"]["limit"]
+
+
+def test_counters_are_every_numeric_engine_counter():
+    before = {"decode_steps": 10, "peak_pages": 3, "slot_occupancy": 0.5,
+              "continuous": True, "engine": "e1", "gone": 4}
+    after = {"decode_steps": 25, "peak_pages": 7, "slot_occupancy": 0.75,
+             "continuous": True, "engine": "e1", "routed_local": 9}
+    delta = sc.counter_deltas(before, after)
+    assert delta == {**dict.fromkeys(sc.COUNTERS, 0), "decode_steps": 15,
+                     "peak_pages": 4, "slot_occupancy": 0.25}
 
 
 class _Req:

@@ -168,6 +168,50 @@ def test_readers_on_a_traced_window():
     assert got["layer_loop_overhead.serve_tok_s"] == pytest.approx(30.0)
 
 
+def test_one_read_gives_the_trace_numbers_of_read_profile(tmp_path):
+    """The traced run reads its profile once, with ``read_spans``: the
+    merged summary's busy time, operation seconds and breakdown are
+    those ``trace.summarize`` gives of ``trace.read_profile`` (a profile
+    recorded here, the CPU's XLA thread lines standing in for a device's
+    operation line)."""
+    import time
+
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
+        for _ in range(5):
+            f(x).block_until_ready()
+            time.sleep(0.002)
+    jax.profiler.stop_trace()
+    path = tr.find_profile(str(tmp_path))
+    where = {"device_prefix": "/host:CPU",
+             "device_lines": ("tf_XLAPjRtCpuClient",)}
+    ops = {"dot": lambda n: n.startswith("dot")}
+    want = tr.summarize(tr.read_profile(path, **where), ops)
+    got = sp.window_summary(sp.read_spans(path, {}, **where), ops)
+    assert want["busy_s"] > 0 and want["ops_s"]["dot"] > 0
+    assert want["breakdown"]["device_ops"] and want["breakdown"]["idle_gaps"]
+    for key in ("window_s", "busy_s", "ops_s", "breakdown"):
+        assert got[key] == want[key]
+    assert {"spans", "idle_by_span", "scopes", "programs"} <= set(got)
+
+
+def test_a_familys_scopes_count_beside_the_harness_own():
+    step = "jit(_step)/decode_step/layers/while/body"
+    dev = [op(0, 10, f"{step}/toy.scope/dot"), op(10, 30, f"{step}/mlp/dot")]
+    prof = profile(dev, [host(tr.WINDOW_SPAN, 0, 100)])
+    family = types.SimpleNamespace(SCOPES=("toy.scope",))
+    assert sp.cell_scopes(types.SimpleNamespace()) == sp.SCOPES
+    assert sp.scope_seconds(prof, 0, 100) == {
+        "layers": pytest.approx(10e-9), "mlp": pytest.approx(20e-9)}
+    assert sp.summarize(prof, sp.cell_scopes(family))["scopes"] == {
+        "toy.scope": pytest.approx(10e-9), "mlp": pytest.approx(20e-9)}
+
+
 def test_span_tree_of_a_small_paged_engine(tmp_path):
     """A paged engine's steps under the profiler on the CPU: one
     ``engine.decode`` and one ``engine.decode.fetch`` per decode step, the
@@ -233,23 +277,23 @@ def test_span_tree_of_a_small_paged_engine(tmp_path):
     assert lo < hi and summary["spans"]["engine.decode"]
 
 
-def test_traced_window_reports_the_span_metrics(monkeypatch, smoke_root):
-    """``bench/trace_spans.py`` on the serving cell at smoke size: the
-    span metric reads the window's spans."""
+def test_traced_window_reports_the_span_metrics(run_script, cpu_peaks,
+                                                smoke_root):
+    """``bench/trace_spans.py`` on the serving cell at smoke size, from
+    the traced run of ``bench/run.py``: the span metric reads the
+    window's spans."""
     import importlib.util
 
-    from bench.harness import checkout, manifest
+    from bench.harness import manifest
     from bench_smoke import REPO, SERVE_CELL
 
-    monkeypatch.setattr(checkout, "setup_process", lambda: None)
-    monkeypatch.setattr(checkout, "enable_cache", lambda: None)
     spec = importlib.util.spec_from_file_location(
         "bench_trace_spans_script", REPO / "bench" / "trace_spans.py")
     script = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(script)
     cell = manifest.find_cell(SERVE_CELL, smoke_root)
-    line = script.report(script.traced_window(cell, 2**33 + 11, 2.0,
-                                              jax.devices()))
+    line = script.report(run_script.measure(cell, 2**33 + 11, 2.0, True,
+                                            jax.devices()))
     assert line["correct"] is True
     assert set(line["metrics"]) == set(sp.METRICS)
     assert line["metrics"]["decode_step_ms.serve_tok_s"] > 0
@@ -260,27 +304,36 @@ def test_traced_window_reports_the_span_metrics(monkeypatch, smoke_root):
     assert line["programs"] == {}
     spans = line["spans"]
     assert spans["engine.decode"]["n"] == spans["engine.decode.fetch"]["n"]
-    assert spans["engine.step"]["n"] >= spans["engine.decode"]["n"] > 0
+    # a span counts where it ends: the window's close can fall after a
+    # step's decode ends and before the step does, leaving that step out
+    assert spans["engine.step"]["n"] >= spans["engine.decode"]["n"] - 1
+    assert spans["engine.decode"]["n"] > 0
     assert spans["service.submit"]["n"] > 0
     assert line["e2e"]["serve_tok_s"] > 0
 
 
-def test_hlo_of_the_warmed_steps_maps_operations_to_scopes(monkeypatch,
-                                                           smoke_root):
-    """Where a trace's operations carry no op_name, the span script maps
-    them through the HLO of the step programs the cell warms."""
-    import importlib.util
+def test_hlo_of_the_warmed_steps_maps_operations_to_scopes(smoke_root):
+    """Where a trace's operations carry no op_name, a traced run maps
+    them through the HLO of the step programs the serving cell warms,
+    taken from the engine's own programs after the window."""
+    from bench.harness import manifest, result
+    from bench.harness.serve_cell import ServeCell
+    from bench_smoke import SERVE_CELL
 
-    from bench.harness import checkout, manifest
-    from bench_smoke import REPO, SERVE_CELL
-
-    monkeypatch.setattr(checkout, "setup_process", lambda: None)
-    spec = importlib.util.spec_from_file_location(
-        "bench_trace_spans_script", REPO / "bench" / "trace_spans.py")
-    script = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(script)
-    cell = manifest.find_cell(SERVE_CELL, smoke_root)
-    names = sp.op_names_from_hlo(script.hlo_texts(cell, 5))
+    run = ServeCell(manifest.find_cell(SERVE_CELL, smoke_root), 5)
+    run.build()
+    run.warm()
+    compiles = result.CompileCounter()
+    compiles.counting = True
+    try:
+        shapes = run.warm_shapes()
+        texts = run.hlo_texts()
+    finally:
+        compiles.counting = False
+        run.free_engine()
+    assert len(texts) == len(shapes)
+    assert compiles.count == 0      # the warmed programs, not new ones
+    names = sp.op_names_from_hlo(texts)
     scopes = {sp.scope_of(n) for n in names.values()}
     assert {"decode_step", "prefill_chunk", "layers", "attn.kv_append",
             "attn.core", "mlp"} <= scopes

@@ -2,6 +2,7 @@
 on small shapes: the Phi-3 family's (``bench/families/phi3.py``) and the
 roofline."""
 import json
+import types
 
 import pytest
 
@@ -40,6 +41,49 @@ def test_attention_work_by_hand():
     assert f == 4 * 3 * 2 * 4 * 6
     assert b == 2 * 3 * 1 * 4 * 2 * 6       # K and V rows, bf16
     assert work.kv_bytes_per_token(DEC) == 2 * 3 * 4 * 2
+
+
+def _window(*requests):
+    """A window from 1.0 to 10.0 that served requests of (prompt, token
+    stamps), the first stamp being the prompt's first token."""
+    from bench.harness.serve_cell import Served
+
+    return {"t0": 1.0, "t1": 10.0, "served": [
+        Served(None, types.SimpleNamespace(prompt_len=p, token_times=ts,
+                                           first_token_at=ts[0]))
+        for p, ts in requests]}
+
+
+# a prompt of 5 whose first token, 2nd and 3rd fall in the window (keys 6,
+# 7), its 4th after it; a prompt of 7 answered before it, 2nd token in it
+WINDOW = ((5, [1.0, 2.0, 3.0, 12.0]), (7, [0.5, 4.0]))
+
+
+def test_window_work_of_the_decoder_by_hand():
+    from bench.harness.serve_cell import window_work
+
+    got = window_work(_window(*WINDOW), DEC, work)
+    # prompt of 5: 2 x 3 x 576 x 5 + 4 x 3 x 2 x 4 x (5 x 6 / 2) + 160;
+    # tokens at keys 6, 7, 8: (3456 + 160 + 4 x 3 x 2 x 4 x keys) each
+    assert got == {"model_flops": (17280 + 1440 + 160)
+                   + (3 * 3616 + 96 * (6 + 7 + 8)),
+                   "decode_attention": (96 * 21, 48 * 21)}
+    assert set(got) == {"model_flops", "decode_attention"}
+
+
+def test_window_work_sums_a_familys_named_work():
+    from bench.harness.serve_cell import window_work
+
+    family = types.SimpleNamespace(
+        prompt_flops=lambda c, p: 0.0,
+        token_flops=lambda c, keys, logits: 0.0,
+        decode_attention_work=lambda c, keys: pytest.fail("not called"),
+        token_work=lambda c, keys: {"latent": (keys, 2.0)},
+        prompt_work=lambda c, p: {"latent": (0.0, 100.0 * p),
+                                  "router": (1.0, 0.0)})
+    got = window_work(_window(*WINDOW), DEC, family)
+    assert got == {"model_flops": 0.0, "latent": (6 + 7 + 8, 500.0 + 6.0),
+                   "router": (1.0, 0.0)}
 
 
 def test_roofline_takes_the_slower_bound():
